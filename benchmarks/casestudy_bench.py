@@ -10,6 +10,7 @@ from benchmarks.common import emit
 from repro.core import SlidingWindowDetector, porting
 from repro.sim import build_dataset, simulate, train_detector
 from repro.sim.msf import SCAN_DT
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(quick: bool = False):
@@ -76,4 +77,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

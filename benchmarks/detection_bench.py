@@ -51,11 +51,13 @@ one-boundary span) by definition, so it is not comparable to the sync p99.
 sharded engine at 1/2/4/8 devices (1/2 under ``--quick``), each device
 owning a ``spec.STREAMS_PER_DEVICE``-plant shard of the fleet (weak
 scaling — the fleet grows with the mesh, which is the fleet-service
-deployment question: how many plants does a d-device mesh serve?).  Each
-device count runs in a child process so ``XLA_FLAGS=
---xla_force_host_platform_device_count`` can fan out host devices; on a
-multi-core host the rows show the aggregate windows/s growing with the
-mesh, and on real multi-chip hardware each shard runs on its own core.
+deployment question: how many plants does a d-device mesh serve?).  All
+device counts run in this one process over prefix meshes of
+``jax.devices()``; a count beyond the visible devices emits an explicit
+skipped row and a warning.  On a CPU host, launch with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to fan out host
+devices.  Every other row pins ``shard=False``, so its meaning does not
+depend on how many devices the process sees.
 
 ``benchmarks/run.py`` persists the returned rows as ``BENCH_detection.json``
 (the fused-vs-per-layer + device-scaling perf record).
@@ -66,9 +68,7 @@ Run:  PYTHONPATH=src python benchmarks/detection_bench.py [--quick]
 from __future__ import annotations
 
 import argparse
-import json
 import os
-import subprocess
 import sys
 import time
 
@@ -83,6 +83,8 @@ import numpy as np
 from benchmarks.common import emit
 from repro.configs import msf_detector as spec
 from repro.core import quantize
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_fleet_mesh
 from repro.serving import GroupedStreamEngine, ModelGroup, StreamEngine
 from repro.sim import (ForecastHead, MarginHead, ReconstructionHead,
                        build_autoencoder, build_detector, build_forecaster,
@@ -118,7 +120,7 @@ def run_engine_pair(model, params, readings, *, stride: int,
     engines = {}
     for fused in (False, True):
         eng = StreamEngine(model, params, n_streams=n_streams, stride=stride,
-                           fused=fused, head=head)
+                           fused=fused, head=head, shard=False)
         eng.warmup()
         for c in range(min(spec.WINDOW, n_cycles)):
             eng.ingest(readings[c % n_cycles])
@@ -168,7 +170,7 @@ def run_sustained_pair(model, params, readings, *, stride: int,
     engines = {}
     for depth in (0, 1):
         eng = StreamEngine(model, params, n_streams=n_streams, stride=stride,
-                           fused=True, async_depth=depth)
+                           fused=True, async_depth=depth, shard=False)
         eng.warmup()
         for c in range(min(spec.WINDOW, n_cycles)):   # ring fill, uncounted
             eng.ingest(readings[c % n_cycles])
@@ -299,10 +301,11 @@ def run_grouped_pair(detectors, readings, *, stride: int,
     n_per = n_streams // len(detectors)
     groups = [ModelGroup(name, m, p, n_per, head)
               for name, m, p, head in detectors]
-    ge = GroupedStreamEngine(groups, stride=stride, megakernel=False)
+    ge = GroupedStreamEngine(groups, stride=stride, shard=False,
+                             megakernel=False)
     ge.warmup()
     splits = [(i * n_per, StreamEngine(m, p, n_streams=n_per, stride=stride,
-                                       head=head))
+                                       head=head, shard=False))
               for i, (name, m, p, head) in enumerate(detectors)]
     for eng in (e for _, e in splits):
         eng.warmup()
@@ -419,7 +422,7 @@ def run_drift_pair(model, params, readings, *, stride: int,
     engines = {}
     for adaptive in (False, True):
         eng = StreamEngine(model, params, n_streams=n_streams, stride=stride,
-                           fused=True, head=head,
+                           fused=True, head=head, shard=False,
                            adapt=adaptive or None)
         eng.warmup()
         for c in range(min(spec.WINDOW, n_cycles)):
@@ -462,26 +465,12 @@ def synthetic_readings(n_streams: int, n_cycles: int, seed: int) -> np.ndarray:
             .astype(np.float32) * np.asarray(spec.NORM_STD, np.float32))
 
 
-def shard_worker(n_devices: int, n_streams: int, n_cycles: int,
-                 workload: str = "mlp") -> None:
-    """One device-scaling measurement, run in a child process whose
-    XLA_FLAGS fanned out ``n_devices`` host devices.  Prints a single
-    ``SHARD_ROW {json}`` line for the parent to collect.  ``workload``
-    picks the classifier (``mlp``) or the reconstruction autoencoder
-    (``ae`` — served through its head's on-device score reduction)."""
-    from repro.launch.mesh import make_fleet_mesh
-
-    if len(jax.devices()) < n_devices:
-        raise RuntimeError(
-            f"worker needs {n_devices} devices, sees {len(jax.devices())}")
-    model = build_autoencoder() if workload == "ae" else build_detector()
-    params = model.init_params(jax.random.PRNGKey(0))
-    calib = [jnp.asarray(np.random.default_rng(1).normal(size=spec.INPUT_SIZE)
-                         .astype(np.float32)) for _ in range(8)]
-    params = quantize.quantize_params(model, params, "SINT",
-                                      calibration=calib)
-    head = (ReconstructionHead(threshold=BENCH_AE_THRESHOLD)
-            if workload == "ae" else None)
+def scaling_point(model, params, head, n_devices: int, n_streams: int,
+                  n_cycles: int) -> dict:
+    """One device-scaling measurement on a prefix mesh of ``n_devices`` of
+    this process's devices.  ``head`` picks the classifier (None) or the
+    reconstruction autoencoder (served through its head's on-device score
+    reduction)."""
     readings = synthetic_readings(n_streams, n_cycles, seed=n_devices)
     # Timed as a full serve lifecycle — cold ring, fill cycles, verdicts —
     # because that's the deployment question the mesh answers: cycles of
@@ -500,42 +489,38 @@ def shard_worker(n_devices: int, n_streams: int, n_cycles: int,
         wall = time.perf_counter() - t0
         if best is None or wall < best[1]:
             best = (eng.stats.windows, wall, eng.stats.latency_p(99))
-    print("SHARD_ROW " + json.dumps({
-        "devices": n_devices, "streams": n_streams,
-        "windows": best[0], "wall_s": best[1],
-        "p99_s": best[2]}), flush=True)
+    return {"devices": n_devices, "streams": n_streams,
+            "windows": best[0], "wall_s": best[1], "p99_s": best[2]}
 
 
 def run_scaling(quick: bool, workload: str = "mlp") -> list:
-    """Fan out one child per device count; return the scaling Rows."""
+    """Device-scaling Rows over prefix meshes of ``jax.devices()``.
+
+    Every device count runs in this process: one process holds the chips.
+    A count beyond the visible devices gets an explicit skipped row (zero
+    ``us_per_call``, which ``run.py --compare`` passes over) and a warning;
+    on a CPU host, launch with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=<n>`` to fan out
+    host devices."""
     if workload == "ae":
-        counts = (1, 2) if quick else (1, 2, 4)
+        wanted = (1, 2) if quick else (1, 2, 4)
     else:
-        counts = (1, 2) if quick else (1, 2, 4, 8)
+        wanted = (1, 2) if quick else (1, 2, 4, 8)
+    n_visible = len(jax.devices())
+    counts = [d for d in wanted if d <= n_visible]
     # Long enough that verdict steps dominate the lifecycle (the fill is
     # 200 of these cycles); scaling rows keep it fixed across --quick so
     # records stay comparable.
     n_cycles = 1200
     prefix = "detect_ae_shard" if workload == "ae" else "detect_fleet_shard"
-
-    def spawn(d):
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = env.get("JAX_PLATFORMS", "cpu")
-        if d > 1:
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                f" --xla_force_host_platform_device_count={d}").strip()
-        cmd = [sys.executable, os.path.abspath(__file__), "--shard-worker",
-               "--devices", str(d), "--workload", workload,
-               "--streams", str(spec.STREAMS_PER_DEVICE * d),
-               "--cycles", str(n_cycles)]
-        out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                             timeout=1800)
-        if out.returncode != 0:
-            sys.stderr.write(out.stderr)
-            raise RuntimeError(f"shard worker (devices={d}) failed")
-        line = [ln for ln in out.stdout.splitlines()
-                if ln.startswith("SHARD_ROW ")][-1]
-        return json.loads(line[len("SHARD_ROW "):])
+    model = build_autoencoder() if workload == "ae" else build_detector()
+    params = model.init_params(jax.random.PRNGKey(0))
+    calib = [jnp.asarray(np.random.default_rng(1).normal(size=spec.INPUT_SIZE)
+                         .astype(np.float32)) for _ in range(8)]
+    params = quantize.quantize_params(model, params, "SINT",
+                                      calibration=calib)
+    head = (ReconstructionHead(threshold=BENCH_AE_THRESHOLD)
+            if workload == "ae" else None)
 
     # Three interleaved sweeps, median wall per device count: a transient
     # load burst on a shared CI box then taxes sweeps, not device counts,
@@ -543,7 +528,9 @@ def run_scaling(quick: bool, workload: str = "mlp") -> list:
     samples = {d: [] for d in counts}
     for _ in range(3):
         for d in counts:
-            samples[d].append(spawn(d))
+            samples[d].append(scaling_point(
+                model, params, head, d, spec.STREAMS_PER_DEVICE * d,
+                n_cycles))
     results = [sorted(samples[d], key=lambda r: r["wall_s"])[1]
                for d in counts]
 
@@ -559,6 +546,13 @@ def run_scaling(quick: bool, workload: str = "mlp") -> list:
                        f"vs_1dev={wps / wps_1dev:.2f}x"})
         print(f"# {workload} shard d{r['devices']}: {r['streams']} plants, "
               f"{wps:.0f} windows/s ({wps / wps_1dev:.2f}x vs 1 device)")
+    for d in wanted[len(counts):]:
+        rows.append({"name": f"{prefix}_d{d}", "us_per_call": 0.0,
+                     "derived": f"skipped=process sees {n_visible} devices"})
+        print(f"# WARNING {workload} shard d{d} skipped: this process sees "
+              f"{n_visible} devices (set XLA_FLAGS="
+              f"--xla_force_host_platform_device_count={d} on a CPU host)",
+              file=sys.stderr, flush=True)
     return rows
 
 
@@ -736,18 +730,10 @@ def main(quick: bool = False, n_streams: int = 16, n_cycles: int = 0):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--streams", type=int, default=16)
     ap.add_argument("--cycles", type=int, default=0)
-    ap.add_argument("--shard-worker", action="store_true",
-                    help="internal: one device-scaling measurement "
-                         "(spawned by run_scaling with XLA_FLAGS set)")
-    ap.add_argument("--devices", type=int, default=1)
-    ap.add_argument("--workload", default="mlp", choices=("mlp", "ae"),
-                    help="internal: shard-worker model kind")
     a = ap.parse_args()
-    if a.shard_worker:
-        shard_worker(a.devices, a.streams, a.cycles, a.workload)
-    else:
-        main(quick=a.quick, n_streams=a.streams, n_cycles=a.cycles)
+    main(quick=a.quick, n_streams=a.streams, n_cycles=a.cycles)

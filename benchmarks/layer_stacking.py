@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from benchmarks.common import emit, linear_fit, time_fn
 from repro.configs.icsml_mlp import BENCH_FEATURES
 from repro.core import layers as L, sequential
+from repro.launch.compile_cache import enable_compile_cache
 
 DEPTHS = (1, 2, 4, 8, 16, 32)
 
@@ -59,4 +60,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

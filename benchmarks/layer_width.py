@@ -7,6 +7,7 @@ import jax
 
 from benchmarks.common import emit, linear_fit, time_fn
 from repro.core import layers as L, sequential
+from repro.launch.compile_cache import enable_compile_cache
 
 WIDTHS = (32, 64, 128, 256, 512, 1024)
 
@@ -31,4 +32,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

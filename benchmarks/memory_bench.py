@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from benchmarks.common import emit
 from repro.core import layers as L, memory, quantize, sequential
+from repro.launch.compile_cache import enable_compile_cache
 
 PAPER_TABLE2 = {
     "SINT": 266_244, "INT": 528_388, "DINT": 1_052_676, "REAL": 1_050_624,
@@ -78,4 +79,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

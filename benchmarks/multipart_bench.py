@@ -14,6 +14,7 @@ import numpy as np
 from benchmarks.common import emit, time_fn
 from repro.core import layers as L, runtime, sequential
 from repro.sim.detector import build_detector
+from repro.launch.compile_cache import enable_compile_cache
 
 SEGMENTS = (1, 2, 4, 8)
 
@@ -66,4 +67,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

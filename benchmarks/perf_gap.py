@@ -20,6 +20,7 @@ import jax
 
 from benchmarks.common import emit, time_fn
 from repro.core import layers as L, sequential
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(quick: bool = False):
@@ -52,4 +53,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ from benchmarks.common import emit, time_fn
 from repro.configs.icsml_mlp import PRUNE_LAYER
 from repro.core import prune
 from repro.kernels import ops
+from repro.launch.compile_cache import enable_compile_cache
 
 SPARSITIES = (0.0, 0.25, 0.5, 0.75)
 
@@ -55,4 +56,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from benchmarks.common import emit, time_fn
 from repro.core import layers as L, quantize, sequential
 from repro.configs.icsml_mlp import QUANT_LAYER
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(quick: bool = False):
@@ -58,4 +59,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

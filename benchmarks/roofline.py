@@ -23,6 +23,7 @@ from typing import Dict, List, Optional
 
 from benchmarks.common import emit
 from repro.configs.base import INPUT_SHAPES, get_config
+from repro.launch.compile_cache import enable_compile_cache
 
 PEAK_BF16 = 197e12
 PEAK_INT8 = 394e12
@@ -183,4 +184,5 @@ def main(quick: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,8 @@ _ROOT = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, os.path.join(_ROOT, "src"))
 sys.path.insert(0, _ROOT)
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+
 MODULES = [
     ("layer_stacking", "Fig.4/§5.2"),
     ("layer_width", "§5.3"),
@@ -154,4 +156,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,6 +25,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.models import get_model
 from repro.serving import ContinuousEngine, Engine, Request
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def skewed_requests(n: int, *, prompt_len: int, short_new: int, long_new: int,
@@ -110,6 +111,7 @@ def main(quick: bool = False, arch: str = "qwen3_8b", requests: int = 0,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--arch", default="qwen3_8b")

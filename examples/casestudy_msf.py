@@ -30,6 +30,7 @@ from repro.core import ScanCycleRuntime, SlidingWindowDetector, porting, quantiz
 from repro.core.runtime import MultipartInference
 from repro.sim import build_dataset, simulate, train_detector
 from repro.sim.msf import SCAN_DT, CascadePID, adc
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -133,4 +134,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

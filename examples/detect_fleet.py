@@ -98,6 +98,7 @@ from repro.sim import (SCENARIOS, ParamDrift, build_dataset, build_fleet,
                        train_one_class)
 from repro.sim.msf import SCAN_DT
 from repro.serving import GroupedStreamEngine, ModelGroup, StreamEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _budget(fast: bool, smoke: bool):
@@ -393,4 +394,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import MultipartInference, layers as L, prune, quantize, sequential
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

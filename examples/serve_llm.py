@@ -24,6 +24,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config
 from repro.models import get_model
 from repro.serving import ContinuousEngine, CyclicDecoder, Engine, Request
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -93,4 +94,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

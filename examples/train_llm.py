@@ -26,6 +26,7 @@ from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLM
 from repro.launch.steps import make_optimizer, make_train_step
 from repro.models import get_model
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -75,4 +76,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -46,5 +46,5 @@ FLEET_STREAMS = 16
 
 # Stream-axis sharding: per-device shard of the fleet arena used by the
 # device-scaling benchmark rows (a d-device mesh serves d x this many
-# plants; benchmarks/detection_bench.py --shard-worker).
+# plants; benchmarks/detection_bench.py run_scaling).
 STREAMS_PER_DEVICE = 128

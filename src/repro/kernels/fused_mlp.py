@@ -26,6 +26,11 @@ Layer kinds (mirroring ``layers._quantized_matvec`` / §6.1 semantics):
   (INT/DINT)          emulated in f32 (no int16/int32 MXU mode — DESIGN.md §2),
                       rescale+bias.
 
+Every f32 dot runs at ``F32_DOT`` (``Precision.HIGHEST``): the MXU's default
+f32 contraction is one bf16 pass, which keeps 8 significant bits of each
+operand — REAL would no longer be f32, and the INT/DINT integer grid would
+not survive the operand rounding.
+
 Grid: ``(M/block_m, K0/block_k)`` — rows tile as before, and the **first
 layer is K-gridded**: its input width (the detector's 400-wide window — the
 widest dimension of both §7 workloads) streams through VMEM one
@@ -56,7 +61,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.layers import ACTIVATIONS
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
+
+# Full f32 contraction for every f32 dot in these kernels (see above).
+F32_DOT = jax.lax.Precision.HIGHEST
 
 # Softmax normalizes across the (padded) lane axis, so it cannot run on
 # zero-padded tiles without masking; every other §4.1 activation is
@@ -163,7 +170,7 @@ def _fused_kernel(*refs, modes: Sequence[str], acts: Sequence[str],
         idx += 2
         acc_ref[...] += jax.lax.dot_general(
             x_ref[...], w0_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=F32_DOT, preferred_element_type=jnp.float32,
         )
 
         def _finish0(acc):
@@ -185,6 +192,7 @@ def _fused_kernel(*refs, modes: Sequence[str], acts: Sequence[str],
         else:
             acc_ref[...] += jax.lax.dot_general(
                 hq, w0_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
+                precision=F32_DOT,
             )
 
         def _finish0(acc):
@@ -202,7 +210,7 @@ def _fused_kernel(*refs, modes: Sequence[str], acts: Sequence[str],
                 i += 2
                 h = jax.lax.dot_general(
                     h, w_ref[...], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
+                    precision=F32_DOT, preferred_element_type=jnp.float32,
                 ) + b_ref[...]
             else:
                 xs_ref, w_ref, s_ref, b_ref = rest[i:i + 4]
@@ -223,7 +231,7 @@ def _fused_kernel(*refs, modes: Sequence[str], acts: Sequence[str],
                     # overflows).
                     acc = jax.lax.dot_general(
                         hq, w_ref[...].astype(jnp.float32),
-                        (((1,), (0,)), ((), ())),
+                        (((1,), (0,)), ((), ())), precision=F32_DOT,
                     )
                 # Fused dequant epilogue: REAL rescale + bias, still in VMEM.
                 h = acc * s_ref[...] + b_ref[...]
@@ -327,7 +335,7 @@ def fused_mlp(
         out_specs=pl.BlockSpec((block_m, n_last), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n_last), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, n1), acc_dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -341,8 +349,10 @@ def fused_mlp(
 # The grouped-GEMM / MoE-expert-batching idea applied to the detector zoo:
 # every group's (padded) weight/bias/scale slabs for layer position l live in
 # one (G, K_l, N_l) arena, the grid spans (group, M-blocks), and per-group
-# geometry is resolved by index maps plus a small SMEM scalar table — kind,
-# true output width, activation id and skip flag per position.  Groups
+# geometry is resolved by index maps plus small SMEM scalar tables — kind,
+# true output width, activation id and skip flag per position, and each
+# position's activation scale — passed whole and read at the group's row
+# (``pl.program_id(0)``).  Groups
 # shallower than the deepest stack "skip" their trailing positions: the SMEM
 # flag passes activations through untouched, and the union width at those
 # positions is kept at least as wide as every finished group's true output so
@@ -398,13 +408,17 @@ def _grouped_kernel(*refs, modes: Sequence[str], qmaxes: Sequence[int],
     """One (group, M-block) grid step: the group's whole stack + epilogue.
 
     Ref order: meta (SMEM), x, then per position (x_scale SMEM, w, scale,
-    bias), then tgt, out.  ``meta`` rows are
-    ``[kind, n_out_true, act_id * L, skip * L]``.
+    bias), then tgt, out.  The SMEM tables arrive whole — ``meta`` (G, 2+2L)
+    with rows ``[kind, n_out_true, act_id * L, skip * L]`` and each
+    position's (G, 1) ``x_scale`` — and the kernel reads its group's row at
+    ``pl.program_id(0)``: the TPU lowering accepts no (1, C) block over
+    them.
     """
     meta_ref, x_ref = refs[0], refs[1]
     tgt_ref, out_ref = refs[-2], refs[-1]
-    kind = meta_ref[0, 0]
-    n_out = meta_ref[0, 1]
+    gi = pl.program_id(0)
+    kind = meta_ref[gi, 0]
+    n_out = meta_ref[gi, 1]
     h = x_ref[0]
     for l in range(n_layers):
         xs_ref, w_ref, s_ref, b_ref = refs[2 + 4 * l: 6 + 4 * l]
@@ -412,10 +426,10 @@ def _grouped_kernel(*refs, modes: Sequence[str], qmaxes: Sequence[int],
         if modes[l] == "real":
             y = jax.lax.dot_general(
                 h, w, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
+                precision=F32_DOT, preferred_element_type=jnp.float32,
             ) + b_ref[0]
         else:
-            hq = jnp.clip(jnp.round(h / xs_ref[0, 0]),
+            hq = jnp.clip(jnp.round(h / xs_ref[gi, 0]),
                           -qmaxes[l], qmaxes[l])
             if modes[l] == "int8":
                 acc = jax.lax.dot_general(
@@ -425,12 +439,13 @@ def _grouped_kernel(*refs, modes: Sequence[str], qmaxes: Sequence[int],
             else:
                 acc = jax.lax.dot_general(
                     hq, w.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                    precision=F32_DOT,
                 )
             y = acc * s_ref[0] + b_ref[0]
         # Per-group activation: select among the distinct activations used at
         # this position by the SMEM act id (statically unrolled — typically
         # one).  Softmax is masked to the group's true output width.
-        act_id = meta_ref[0, 2 + l]
+        act_id = meta_ref[gi, 2 + l]
         out_l = y
         for name in pos_acts[l]:
             if name == "softmax":
@@ -448,7 +463,7 @@ def _grouped_kernel(*refs, modes: Sequence[str], qmaxes: Sequence[int],
         # Skip pass-through for groups shallower than this position: carry
         # the previous activations (their true payload sits in the leading
         # lanes; the union width never truncates it).
-        skip = meta_ref[0, 2 + n_layers + l]
+        skip = meta_ref[gi, 2 + n_layers + l]
         n_l = out_l.shape[1]
         prev = h
         if prev.shape[1] < n_l:
@@ -465,7 +480,10 @@ def _grouped_kernel(*refs, modes: Sequence[str], qmaxes: Sequence[int],
     lanes = jax.lax.broadcasted_iota(jnp.int32, h.shape, 1)
     d = jnp.where(lanes < n_out, h - tgt, 0.0)
     score = jnp.sum(d * d, axis=1, keepdims=True) / n_out.astype(jnp.float32)
-    pay_score = jnp.where(lanes[:, :n_pay] == 0, score, 0.0)
+    # A payload-wide iota of its own: slicing ``lanes`` down to n_pay aborts
+    # the TPU compiler (jaxlib 0.9) when n_pay < the last union width.
+    pay_lanes = jax.lax.broadcasted_iota(jnp.int32, (h.shape[0], n_pay), 1)
+    pay_score = jnp.where(pay_lanes == 0, score, 0.0)
     out_ref[0] = jnp.where(kind == GROUPED_KIND_LOGITS,
                            h[:, :n_pay], pay_score)
 
@@ -490,8 +508,11 @@ def grouped_fused_mlp(
         by ``block_m``, K0 and all arena dims padded to the 128-lane tile.
       layers: :class:`GroupedLayer` arenas per position; position l's
         ``w.shape[1]`` feeds position l+1's ``w.shape[2]``.
-      meta: (G, 2 + 2L) int32 SMEM table —
-        ``[kind, n_out_true, act_id x L, skip x L]`` per group.
+      meta: (G, 2 + 2L) int32 table —
+        ``[kind, n_out_true, act_id x L, skip x L]`` per group.  It and
+        every position's (G, 1) ``x_scale`` go to SMEM whole (the TPU
+        lowering accepts no ``(1, C)`` block over them); the kernel reads
+        row ``pl.program_id(0)``.
       tgt: (G, M, N_last) f32 epilogue targets at the last position's union
         width (window / tail / center rows; zeros for classifiers).
       n_pay: payload lane count (128-padded max over groups: a classifier's
@@ -530,19 +551,19 @@ def grouped_fused_mlp(
             f"grouped arena needs ~{vmem} B of VMEM resident (> "
             f"{VMEM_BUDGET_BYTES}); fall back to per-group dispatch")
 
-    meta_cols = meta.shape[1]
+    # The scalar tables ride whole in SMEM (no block shape); the kernel
+    # indexes its group's row by program id.
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     operands = [meta, x]
     in_specs = [
-        pl.BlockSpec((1, meta_cols), lambda gi, i: (gi, 0),
-                     memory_space=pltpu.SMEM),
+        smem,
         pl.BlockSpec((1, block_m, k0), lambda gi, i: (gi, i, 0)),
     ]
     for layer in layers:
         _, k, n = layer.w.shape
         operands += [layer.x_scale, layer.w, layer.scale, layer.bias]
         in_specs += [
-            pl.BlockSpec((1, 1), lambda gi, i: (gi, 0),
-                         memory_space=pltpu.SMEM),
+            smem,
             pl.BlockSpec((1, k, n), lambda gi, i: (gi, 0, 0)),
             pl.BlockSpec((1, 1, n), lambda gi, i: (gi, 0, 0)),
             pl.BlockSpec((1, 1, n), lambda gi, i: (gi, 0, 0)),
@@ -560,7 +581,7 @@ def grouped_fused_mlp(
         out_specs=pl.BlockSpec((1, block_m, n_pay),
                                lambda gi, i: (gi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((g, m, n_pay), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel"),
         ),
         interpret=interpret,

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 
 def _ssd_kernel(
@@ -125,7 +124,7 @@ def ssd_scan(
         out_specs=pl.BlockSpec((chunk, 1, p), lambda hh, cc: (cc, hh, 0)),
         out_shape=jax.ShapeDtypeStruct((t, h, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -15,6 +15,14 @@ from typing import Tuple
 import jax
 
 
+def _auto(n: int) -> tuple:
+    """Automatic axes: ``jax.make_mesh`` defaults to ``Explicit`` sharding,
+    which the pjit train step and the fleet's ``shard_map`` steps were not
+    written for (an explicit-axis embedding gather raises
+    ``ShardingTypeError``)."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     import numpy as np
 
@@ -27,12 +35,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             f"mesh {shape} needs {n} devices but only {len(devices)} present; "
             "the dry-run launcher sets xla_force_host_platform_device_count=512"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)),
+                         devices=devices[:n])
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """A 1×1 mesh over the local device — smoke tests of the pjit path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(2))
 
 
 def make_fleet_mesh(n_devices: int | None = None, *,
@@ -63,7 +72,8 @@ def make_fleet_mesh(n_devices: int | None = None, *,
                 f"fleet mesh needs 1..{len(devices)} devices, asked for {n}; "
                 "set XLA_FLAGS=--xla_force_host_platform_device_count=<n> to "
                 "fan out host devices")
-        return jax.make_mesh((n,), ("data",), devices=devices[:n])
+        return jax.make_mesh((n,), ("data",), axis_types=_auto(1),
+                             devices=devices[:n])
     n_data = (len(devices) // model_shards if n_devices is None
               else n_devices)
     need = n_data * model_shards
@@ -74,7 +84,7 @@ def make_fleet_mesh(n_devices: int | None = None, *,
             "XLA_FLAGS=--xla_force_host_platform_device_count=<n> to fan "
             "out host devices")
     return jax.make_mesh((n_data, model_shards), ("data", "model"),
-                         devices=devices[:need])
+                         axis_types=_auto(2), devices=devices[:need])
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

@@ -105,7 +105,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import msf_detector as spec
@@ -840,9 +839,9 @@ class ServingCore:
             # included), so each device serves its contiguous shard of every
             # ready unit; the only collectives are the model-axis gathers of
             # column-sharded wide layers (none on a 1-D mesh).
-            # check_rep=False: pallas_call carries no replication rule.
+            # check_vma=False: pallas_call carries no replication rule.
             n = len(key)
-            _step = shard_map(
+            _step = jax.shard_map(
                 _step, mesh=self.mesh,
                 in_specs=((P("data", None, None),) * n,
                           (P("data", None),) * n, (P("data"),) * n,
@@ -851,7 +850,7 @@ class ServingCore:
                 out_specs=((P("data", None, None),) * n,
                            (P("data", None),) * n, (P("data"),) * n,
                            (P("data", None),) * n),
-                check_rep=False)
+                check_vma=False)
         step = self._steps[key] = jax.jit(_step, donate_argnums=(0, 1, 2))
         return step
 
@@ -884,8 +883,8 @@ class ServingCore:
             out_specs = (P("data", None, None), P("data", None))
             donate = 0
         if self.mesh is not None:
-            step = shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+            step = jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)
         return jax.jit(step, donate_argnums=donate)
 
     # -- megakernel: the whole ready fleet in ONE dispatch -----------------
@@ -1053,9 +1052,9 @@ class ServingCore:
             # Rings/calib state keep their per-unit P("data", ...) specs;
             # the stacked block and payload shard their STREAM axis (axis
             # 1); packed arenas, meta, centers, positions and thresholds
-            # are replicated operands.  check_rep=False: pallas_call
+            # are replicated operands.  check_vma=False: pallas_call
             # carries no replication rule.
-            _mega = shard_map(
+            _mega = jax.shard_map(
                 _mega, mesh=self.mesh,
                 in_specs=((P("data", None, None),) * n,
                           (P("data", None),) * n, (P("data"),) * n,
@@ -1063,7 +1062,7 @@ class ServingCore:
                 out_specs=((P("data", None, None),) * n,
                            (P("data", None),) * n, (P("data"),) * n,
                            P(None, "data", None)),
-                check_rep=False)
+                check_vma=False)
         step = jax.jit(_mega, donate_argnums=(0, 1, 2))
         self._mega_steps[cache_key] = step
         return step, pack
@@ -1095,7 +1094,9 @@ class ServingCore:
             tuple(self._calibs[gi] for gi, _ in key),
             tuple(self._counts[gi] for gi, _ in key),
             self._place(np.stack(blocks), self._block4_sharding),
-            jnp.asarray(poss, jnp.int32), jnp.asarray(thrs, jnp.float32),
+            # Host numpy operands: jnp.asarray of a Python list would
+            # compile a conversion on the first hot-path step.
+            np.asarray(poss, np.int32), np.asarray(thrs, np.float32),
             pack.arrays, pack.centers)
         for (gi, _), ring, calib, counts in zip(key, new_rings, new_calibs,
                                                 new_counts):
@@ -1151,20 +1152,18 @@ class ServingCore:
                 keys.append(tuple(key))
         return keys
 
-    def warmup(self) -> None:
-        """Compile every step shape the readiness schedule can produce —
-        each unit's window-fill firing and the steady-state all-ready step
-        — outside the serve clock, with the serve-time arena sharding.
+    def _step_examples(self):
+        """Yield ``(jitted step, zeroed operands)`` for every step shape the
+        readiness schedule can produce — what :meth:`warmup` compiles, and
+        what a caller lowers to inspect the programs serving will run.
 
         Routing mirrors :meth:`ingest`: multi-unit uniform-geometry keys
-        compile the megakernel step (cached per BLOCK SHAPE, so distinct
-        ready-combinations of equal shape compile once), everything else
-        the per-group step."""
+        yield the megakernel step (cached per BLOCK SHAPE, so distinct
+        ready-combinations of equal shape share one), everything else the
+        per-group step."""
         for key in self._schedule_keys():
             if self._mega_applicable(key):
-                step, args = self._mega_example_args(key)
-                *_, payload = step(*args)
-                jax.block_until_ready(payload)
+                yield self._mega_example_args(key)
                 continue
             rings = tuple(self._place(jnp.zeros(
                 (self._units[gi].s_pad, self._units[gi].window,
@@ -1175,9 +1174,16 @@ class ServingCore:
                 jnp.float32)) for gi, length in key)
             poss = tuple(jnp.int32(0) for _ in key)
             thrs = tuple(self._thr(self._units[gi]) for gi, _ in key)
-            *_, outs = self._get_step(key)(
+            yield self._get_step(key), (
                 rings, tuple(c for c, _ in states),
                 tuple(n for _, n in states), blocks, poss, thrs)
+
+    def warmup(self) -> None:
+        """Compile every step shape the readiness schedule can produce —
+        each unit's window-fill firing and the steady-state all-ready step
+        — outside the serve clock, with the serve-time arena sharding."""
+        for step, args in self._step_examples():
+            *_, outs = step(*args)
             jax.block_until_ready(outs)
 
     # -- ingestion ---------------------------------------------------------

@@ -21,7 +21,8 @@ from repro.launch.mesh import make_fleet_mesh
 from repro.serving import GroupedStreamEngine, ModelGroup, StreamEngine
 from repro.sim import ReconstructionHead, fleet_readings
 from test_drift import energy_detector
-from test_fused import count_pallas_calls, detector_params, small_detector
+from _jaxpr import count_pallas_calls
+from test_fused import detector_params, small_detector
 from test_streams import identity_probe
 
 N_DEVICES = len(jax.devices())

@@ -19,7 +19,8 @@ from repro.sim import (ClassifierHead, ReconstructionHead, build_autoencoder,
                        fleet_readings, softmax_np, train_autoencoder)
 from repro.sim.detector import batched_forward
 
-from test_fused import autoencoder_params, count_pallas_calls
+from _jaxpr import count_pallas_calls
+from test_fused import autoencoder_params
 
 SCHEMES = ("REAL", "SINT", "INT", "DINT")
 N_DEVICES = len(jax.devices())

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from _hyp import given, settings, st
+from _jaxpr import count_pallas_calls
 from repro.core import layers as L
 from repro.core import quantize, sequential
 from repro.kernels import fused_mlp as fused_mlp_mod
@@ -107,22 +108,6 @@ class TestFusedVsPerLayer:
         want = jax.vmap(model.apply, (None, 0))(params, x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-3, atol=1e-4)
-
-
-def count_pallas_calls(jaxpr) -> int:
-    """Pallas dispatches in a jaxpr, recursing through pjit/scan/etc."""
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            n += 1
-        for v in eqn.params.values():
-            vs = v if isinstance(v, (list, tuple)) else [v]
-            for u in vs:
-                if isinstance(u, jax.core.ClosedJaxpr):
-                    n += count_pallas_calls(u.jaxpr)
-                elif isinstance(u, jax.core.Jaxpr):
-                    n += count_pallas_calls(u)
-    return n
 
 
 class TestSingleDispatch:

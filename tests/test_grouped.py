@@ -20,7 +20,7 @@ from repro.serving import GroupedStreamEngine, ModelGroup, StreamEngine
 from repro.sim import (ClassifierHead, ForecastHead, MarginHead,
                        ReconstructionHead)
 
-from test_fused import count_pallas_calls
+from _jaxpr import count_pallas_calls
 
 SCHEMES = ("REAL", "SINT", "INT", "DINT")
 N_DEVICES = len(jax.devices())

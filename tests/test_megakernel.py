@@ -29,7 +29,7 @@ from repro.launch.mesh import make_fleet_mesh
 from repro.serving import GroupedStreamEngine, ModelGroup, StreamEngine
 from repro.sim import ReconstructionHead
 
-from test_fused import count_pallas_calls
+from _jaxpr import count_pallas_calls
 from test_grouped import NO_NORM, SCHEMES, mixed_groups, small_model
 
 N_DEVICES = len(jax.devices())

@@ -4,8 +4,11 @@
 serving core column-shards every Dense layer whose output width reaches
 ``MODEL_SHARD_MIN_WIDTH`` over the model axis — each rank computes a
 full-K dot for its own slice of output columns and one tiled
-``all_gather`` recombines them, so sharded serving is **bit-exact**
-against the unsharded engine (columns of a matmul are independent).
+``all_gather`` recombines them, so each output column is the same full-K
+dot product as the unsharded engine's (columns of a matmul are
+independent).  The ``(1, 2)`` mesh and every SINT program are bit-exact;
+on ``(2, 2)`` the CPU program rounds some REAL logits an ulp apart, so
+those tests hold REAL PRED exact and tails to the 1e-5 sharded contract.
 Pad-stream data sharding composes unchanged; the fused single-dispatch
 kernel cannot span the gather, so the model axis forces the per-layer
 step.
@@ -21,6 +24,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _jaxpr import count_primitive
 from repro.launch.mesh import make_fleet_mesh, make_host_mesh
 from repro.serving import GroupedStreamEngine, ModelGroup, StreamEngine
 from repro.serving.core import MODEL_SHARD_MIN_WIDTH
@@ -33,23 +37,6 @@ N_DEVICES = len(jax.devices())
 
 needs2 = pytest.mark.skipif(N_DEVICES < 2, reason="needs >= 2 devices")
 needs4 = pytest.mark.skipif(N_DEVICES < 4, reason="needs >= 4 devices")
-
-
-def count_primitive(jaxpr, name):
-    """Occurrences of a primitive anywhere in a jaxpr (recursing into
-    sub-jaxprs: jit / shard_map / scan bodies)."""
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == name:
-            n += 1
-        for v in eqn.params.values():
-            vs = v if isinstance(v, (list, tuple)) else [v]
-            for u in vs:
-                if isinstance(u, jax.core.ClosedJaxpr):
-                    n += count_primitive(u.jaxpr, name)
-                elif isinstance(u, jax.core.Jaxpr):
-                    n += count_primitive(u, name)
-    return n
 
 
 def verdict_key(v):
@@ -113,16 +100,25 @@ class TestModelShardedParity:
     @pytest.mark.parametrize("scheme", ("REAL", "SINT"))
     @pytest.mark.parametrize("n_streams", (4, 5))      # divisible and padded
     def test_detector_parity_data2_model2(self, scheme, n_streams):
+        """SINT bit-exact.  REAL: exact PRED, logits at the 1e-5 sharded
+        contract — the (2, 2) program rounds some REAL logits an ulp
+        (7.45e-9) apart from the unsharded one on 4 host devices."""
         model, params = detector_params(scheme)
         readings = fleet_readings(n_streams, 230, seed=13)
-        logits = {}
+        logits, preds = {}, {}
         for key, kw in (("base", {"shard": False}),
                         ("shard", {"mesh": make_fleet_mesh(2,
                                                            model_shards=2)})):
             eng = StreamEngine(model, params, n_streams=n_streams, **kw)
-            serve_all(eng, readings)
+            preds[key] = [(v.stream, v.cycle, v.pred)
+                          for v in serve_all(eng, readings)]
             logits[key] = eng.last_logits
-        np.testing.assert_array_equal(logits["shard"], logits["base"])
+        assert preds["shard"] == preds["base"]
+        if scheme == "SINT":
+            np.testing.assert_array_equal(logits["shard"], logits["base"])
+        else:
+            np.testing.assert_allclose(logits["shard"], logits["base"],
+                                       rtol=1e-5, atol=1e-6)
 
     def test_identity_window_oracle(self):
         """Ground truth, not just parity: a 64-wide identity layer sharded
@@ -241,6 +237,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import numpy as np
 import jax
 assert len(jax.devices()) == 4, jax.devices()
+from _jaxpr import count_primitive
 from repro.launch.mesh import make_fleet_mesh
 from repro.serving import StreamEngine
 from repro.sim import fleet_readings
@@ -249,14 +246,25 @@ from test_fused import detector_params
 for scheme in ("REAL", "SINT"):
     model, params = detector_params(scheme)
     readings = fleet_readings(5, 230, seed=17)         # 5 plants, (2, 2) mesh
-    logits = {}
+    logits, verdicts = {}, {}
     for key, kw in (("base", {"shard": False}),
                     ("shard", {"mesh": make_fleet_mesh(2, model_shards=2)})):
         eng = StreamEngine(model, params, n_streams=5, **kw)
+        verdicts[key] = []
         for c in range(readings.shape[0]):
-            eng.ingest(readings[c])
+            verdicts[key].extend(eng.ingest(readings[c]))
         logits[key] = eng.last_logits
-    np.testing.assert_array_equal(logits["shard"], logits["base"])
+    base, shard = verdicts["base"], verdicts["shard"]
+    assert [(v.stream, v.cycle, v.pred) for v in shard] == \
+        [(v.stream, v.cycle, v.pred) for v in base]
+    if scheme == "SINT":
+        assert [v.prob for v in shard] == [v.prob for v in base]
+        np.testing.assert_array_equal(logits["shard"], logits["base"])
+    else:
+        np.testing.assert_allclose([v.prob for v in shard],
+                                   [v.prob for v in base], rtol=1e-5)
+        np.testing.assert_allclose(logits["shard"], logits["base"],
+                                   rtol=1e-5, atol=1e-6)
 print("MODEL_MESH_PARITY_OK")
 """
 
@@ -265,8 +273,10 @@ print("MODEL_MESH_PARITY_OK")
                     reason="in-process tests already cover the (2, 2) mesh")
 def test_2x2_parity_subprocess():
     """Single-device environments still certify the (data=2, model=2) mesh:
-    a child process fans out 4 host devices and re-checks bit-exact parity
-    on a non-divisible fleet."""
+    a child process fans out 4 host devices and re-checks parity on a
+    non-divisible fleet — SINT bit-exact; REAL with every PRED identical
+    and f32 tails at the 1e-5 sharded contract (the 4-device CPU program
+    rounds some REAL logits an ulp apart from the 1-device one)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),

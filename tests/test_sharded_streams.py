@@ -28,7 +28,8 @@ from repro.launch.mesh import make_fleet_mesh
 from repro.serving import StreamEngine
 from repro.sim import fleet_readings
 
-from test_fused import count_pallas_calls, detector_params, small_detector
+from _jaxpr import count_pallas_calls
+from test_fused import detector_params, small_detector
 from test_streams import identity_probe
 
 SCHEMES = ("REAL", "SINT", "INT", "DINT")
