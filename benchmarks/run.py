@@ -1,7 +1,6 @@
 """Benchmark harness: one module per paper table/figure (DESIGN.md §6 index).
 
-Prints ``name,us_per_call,derived`` CSV.  ``--quick`` shrinks sweeps; the
-roofline module additionally needs experiments/dryrun artifacts.
+Prints ``name,us_per_call,derived`` CSV.  ``--quick`` shrinks sweeps.
 
 Modules that return their rows also get a machine-readable perf record
 ``BENCH_<name>.json`` written into ``--out-dir`` (e.g. ``BENCH_detection.json``
@@ -41,7 +40,6 @@ MODULES = [
     ("casestudy_bench", "§7"),
     ("serving_bench", "PR1-continuous"),
     ("detection_bench", "§7-fleet"),
-    ("roofline", "§Roofline"),
 ]
 
 

@@ -339,6 +339,7 @@ def fused_mlp(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_mlp",
     )(*operands)
 
 
@@ -585,4 +586,5 @@ def grouped_fused_mlp(
             dimension_semantics=("arbitrary", "parallel"),
         ),
         interpret=interpret,
+        name="grouped_fused_mlp",
     )(*operands)

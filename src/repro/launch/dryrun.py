@@ -6,7 +6,7 @@ os.environ["XLA_FLAGS"] = (
 # ^ MUST precede every other import: jax locks the device count on first init.
 
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
-the production meshes and extract the roofline inputs.
+the production meshes and record what each compiled program costs.
 
 For each case this:
   1. builds the (16,16) single-pod or (2,16,16) multi-pod mesh,
@@ -17,7 +17,7 @@ For each case this:
      coherent (sharding divisibility, collective legality, layout),
   5. records ``memory_analysis()``, ``cost_analysis()`` and the collective
      traffic parsed from the post-SPMD optimized HLO into a JSON blob under
-     ``experiments/dryrun/`` for benchmarks/roofline.py.
+     ``experiments/dryrun/``.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3_8b --shape train_4k
@@ -115,7 +115,7 @@ def effective_config(arch: str, shape: str, quant: Optional[str] = None,
     if unroll:
         # Full unroll of the layer scan: XLA's cost analysis counts a while
         # body once, so honest FLOP/byte/collective totals need the layers in
-        # the HLO.  Compile cost is higher; used by the roofline runs.
+        # the HLO.  Compile cost is higher.
         n_stacked = cfg.n_layers // (cfg.attn_period or 1) if cfg.family == "hybrid" else cfg.n_layers
         cfg = cfg.with_(scan_unroll=max(n_stacked, 1))
     shp = INPUT_SHAPES[shape]

@@ -91,10 +91,3 @@ def data_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:
     """The batch-parallel axes for this mesh ('pod' folds into data)."""
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
 
-
-# v5e hardware constants used by the roofline analysis (benchmarks/roofline).
-PEAK_BF16_FLOPS = 197e12        # per chip
-PEAK_INT8_OPS = 394e12          # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link (~)
-HBM_BYTES = 16e9                # per chip

@@ -93,12 +93,28 @@ the wide layers; weight *storage* sharding is part of the ROADMAP TPU
 validation pass).  The fused whole-MLP kernel cannot span the gather, so
 ``fused=None`` auto-resolves to the per-layer path under a model-sharded
 mesh and ``fused=True`` raises.
+
+**Tracing.**  ``ingest()`` writes host spans with
+``jax.profiler.TraceAnnotation``, on the profiler's clock beside the
+device's ops: ``serve.ingest`` (carrying ``cycle``, the scan cycle's
+index) around each call, and inside it ``serve.normalize``,
+``serve.operands``, ``serve.dispatch`` (carrying ``h2d_bytes``, the host
+operands the step hands the device) and ``serve.finalize`` (carrying the
+``cycle`` of the step it finalizes, so a step's spans share one
+identifier in sync and async mode), which holds ``serve.block``,
+``serve.unpack`` (carrying ``d2h_bytes``, the outputs copied back) and,
+per unit, ``serve.head`` and ``serve.rows``.  A step has a fixed number
+of spans, none per plant; with no trace active each costs well under a
+microsecond.  On the device, each step's ring write and window gather run
+under the named scope ``ring_scatter``.  ``StreamStats.h2d_bytes`` /
+``d2h_bytes`` sum the same byte counts.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -114,6 +130,12 @@ from repro.kernels import ops
 from repro.launch.mesh import make_fleet_mesh
 from repro.sim.heads import (ClassifierHead, DetectorHead, ForecastHead,
                              ScoreHead)
+
+# Host spans on the profiler's clock (a no-op unless a trace is active).
+_span = jax.profiler.TraceAnnotation
+# Host bytes of a per-group step's scalar operands: the int32 write
+# position and the float32 threshold.
+_SCALAR_OPERAND_BYTES = 4 + 4
 
 # Column-shard a Dense layer over the mesh's "model" axis only when its
 # output is at least this wide: below it the all_gather costs more than the
@@ -251,7 +273,12 @@ class StreamStats:
     step is 1 regardless of how many groups co-fired; the per-group path
     charges each ready unit its flavor's cost (fused = 1, per-layer = one
     per Dense layer).  ``dispatches == steps`` is the single-dispatch
-    guarantee the grouped benches assert."""
+    guarantee the grouped benches assert.
+
+    ``h2d_bytes`` counts the host arrays and scalars each verdict step
+    hands the device (pending blocks, write positions, thresholds);
+    ``d2h_bytes`` the step outputs copied back at finalize.  Both are exact
+    and repeat exactly for a given fleet."""
 
     steps: int                       # jitted detector steps executed
     cycles: int                      # scan cycles ingested
@@ -259,6 +286,8 @@ class StreamStats:
     deadline_misses: int
     wall_s: float                    # total time spent inside ingest()
     dispatches: int = 0              # logical kernel dispatches issued
+    h2d_bytes: int = 0               # host->device bytes of step operands
+    d2h_bytes: int = 0               # device->host bytes of step outputs
     latencies_s: LatencyReservoir = dataclasses.field(
         default_factory=LatencyReservoir)
 
@@ -798,15 +827,17 @@ class ServingCore:
             # only the last `window` readings can ever land, and trimming
             # before scattering keeps the indices provably unique
             # (duplicate-index scatter-set order is undefined off-CPU).
-            length = block.shape[1]
-            offset = max(length - w, 0)
-            idx = (pos + offset + jnp.arange(length - offset)) % w
-            ring = ring.at[:, idx, :].set(block[:, offset:])
-            # Window unroll, oldest reading first: the ring holds exactly
-            # the last `window` readings, ending at (pos + L - 1) mod window.
-            end = (pos + length) % w
-            widx = (end + jnp.arange(w)) % w
-            win = jnp.take(ring, widx, axis=1).reshape(ring.shape[0], -1)
+            with jax.named_scope("ring_scatter"):
+                length = block.shape[1]
+                offset = max(length - w, 0)
+                idx = (pos + offset + jnp.arange(length - offset)) % w
+                ring = ring.at[:, idx, :].set(block[:, offset:])
+                # Window unroll, oldest reading first: the ring holds
+                # exactly the last `window` readings, ending at
+                # (pos + L - 1) mod window.
+                end = (pos + length) % w
+                widx = (end + jnp.arange(w)) % w
+                win = jnp.take(ring, widx, axis=1).reshape(ring.shape[0], -1)
             out = head.epilogue(win, _forward(head.prepare(win)))
             if adapt_cfg is not None:
                 # The rolling benign-score state advances INSIDE the donated
@@ -1071,39 +1102,94 @@ class ServingCore:
         """Build operands for a ready-combination, advance per-unit serving
         state and fire the single-dispatch step.  Returns (payload future,
         pack) — the caller wraps them into an :class:`_InFlight`."""
-        sts = [self._units[gi] for gi, _ in key]
-        length = key[0][1]
-        full = np.stack(self._pending[-length:], axis=1)   # (streams, L, F)
-        blocks, poss, thrs = [], [], []
-        for (gi, _), st in zip(key, sts):
-            span = self._count - st.consumed
-            block = full[st.offset:st.offset + st.n_streams]
-            if st.s_pad != st.n_streams:
-                block = np.pad(
-                    block, ((0, st.s_pad - st.n_streams), (0, 0), (0, 0)))
-            blocks.append(block)
-            poss.append((st.pos + (span - length)) % st.window)
-            thrs.append(0.0 if st.live_threshold is None
-                        else st.live_threshold)
-            st.pos = (st.pos + span) % st.window
-            st.consumed = self._count
-            st.fires += 1
-        step, pack = self._get_mega_step(tuple(gi for gi, _ in key), length)
-        new_rings, new_calibs, new_counts, payload = step(
-            tuple(self._rings[gi] for gi, _ in key),
-            tuple(self._calibs[gi] for gi, _ in key),
-            tuple(self._counts[gi] for gi, _ in key),
-            self._place(np.stack(blocks), self._block4_sharding),
+        with _span("serve.operands"):
+            sts = [self._units[gi] for gi, _ in key]
+            length = key[0][1]
+            full = np.stack(self._pending[-length:], axis=1)  # (streams, L, F)
+            blocks, poss, thrs = [], [], []
+            for (gi, _), st in zip(key, sts):
+                span = self._count - st.consumed
+                block = full[st.offset:st.offset + st.n_streams]
+                if st.s_pad != st.n_streams:
+                    block = np.pad(
+                        block,
+                        ((0, st.s_pad - st.n_streams), (0, 0), (0, 0)))
+                blocks.append(block)
+                poss.append((st.pos + (span - length)) % st.window)
+                thrs.append(0.0 if st.live_threshold is None
+                            else st.live_threshold)
+                st.pos = (st.pos + span) % st.window
+                st.consumed = self._count
+                st.fires += 1
+            step, pack = self._get_mega_step(tuple(gi for gi, _ in key),
+                                             length)
+            block = np.stack(blocks)
             # Host numpy operands: jnp.asarray of a Python list would
             # compile a conversion on the first hot-path step.
-            np.asarray(poss, np.int32), np.asarray(thrs, np.float32),
-            pack.arrays, pack.centers)
+            poss = np.asarray(poss, np.int32)
+            thrs = np.asarray(thrs, np.float32)
+            h2d = block.nbytes + poss.nbytes + thrs.nbytes
+            block = self._place(block, self._block4_sharding)
+        self.stats.h2d_bytes += h2d
+        with _span("serve.dispatch", h2d_bytes=h2d):
+            new_rings, new_calibs, new_counts, payload = step(
+                tuple(self._rings[gi] for gi, _ in key),
+                tuple(self._calibs[gi] for gi, _ in key),
+                tuple(self._counts[gi] for gi, _ in key),
+                block, poss, thrs, pack.arrays, pack.centers)
         for (gi, _), ring, calib, counts in zip(key, new_rings, new_calibs,
                                                 new_counts):
             self._rings[gi] = ring
             self._calibs[gi] = calib
             self._counts[gi] = counts
         return payload, pack
+
+    def _dispatch_pergroup(self, ready) -> Tuple[List, Any]:
+        """Build each ready unit's operands, advance its serving state and
+        fire the per-group step.  Returns (step key, output futures)."""
+        with _span("serve.operands"):
+            key, rings, calibs, countss, blocks, poss, thrs = \
+                [], [], [], [], [], [], []
+            h2d = 0
+            for gi, st in ready:
+                # span = cycles elapsed since the unit's last fired step;
+                # the pruned pending tail holds at least the last
+                # min(span, window) readings.
+                span = self._count - st.consumed
+                length = min(span, st.window)
+                block = np.stack(self._pending[-length:], axis=1)  # (S,L,F)
+                block = block[st.offset:st.offset + st.n_streams]
+                if st.s_pad != st.n_streams:
+                    block = np.pad(
+                        block,
+                        ((0, st.s_pad - st.n_streams), (0, 0), (0, 0)))
+                # The ring write always ends at (pos + span - 1) mod window;
+                # host-side trimming of long spans shifts the start to
+                # match.
+                eff_pos = (st.pos + (span - length)) % st.window
+                key.append((gi, length))
+                rings.append(self._rings[gi])
+                calibs.append(self._calibs[gi])
+                countss.append(self._counts[gi])
+                blocks.append(self._place(block))
+                poss.append(jnp.int32(eff_pos))
+                thrs.append(self._thr(st))
+                h2d += block.nbytes + _SCALAR_OPERAND_BYTES
+                st.pos = (st.pos + span) % st.window
+                st.consumed = self._count
+                st.fires += 1
+            step = self._get_step(tuple(key))
+        self.stats.h2d_bytes += h2d
+        with _span("serve.dispatch", h2d_bytes=h2d):
+            new_rings, new_calibs, new_counts, outs = step(
+                tuple(rings), tuple(calibs), tuple(countss), tuple(blocks),
+                tuple(poss), tuple(thrs))
+        for (gi, _), ring, calib, counts in zip(key, new_rings, new_calibs,
+                                                new_counts):
+            self._rings[gi] = ring
+            self._calibs[gi] = calib
+            self._counts[gi] = counts
+        return key, outs
 
     def _mega_example_args(self, key: Tuple) -> Tuple[Callable, Tuple]:
         """(step, zeroed operands) for a ready-combination's megakernel
@@ -1202,25 +1288,33 @@ class ServingCore:
         boundary's step without blocking on it.
         """
         t0 = time.perf_counter()
-        readings = np.asarray(readings, np.float32)
-        if readings.shape != (self.n_streams, self.n_features):
-            raise ValueError(
-                f"expected ({self.n_streams}, {self.n_features}) readings, "
-                f"got {readings.shape}")
-        self._pending.append((readings - self._mean) / self._std)
-        # stride > window: readings older than the last `max_window` can
-        # never land in any ring, so drop them HERE — host memory,
-        # host->device transfer and the compiled block shapes all stay
-        # capped at the window.
-        if len(self._pending) > self.max_window:
-            del self._pending[:len(self._pending) - self.max_window]
+        with _span("serve.ingest", cycle=self._count):
+            verdicts = self._ingest(readings, t0)
+        self.stats.wall_s += time.perf_counter() - t0
+        return verdicts
+
+    def _ingest(self, readings: np.ndarray, t0: float) -> List[Verdict]:
+        """The body of :meth:`ingest`; ``t0`` is the call's start, the
+        latency origin of a step dispatched here."""
+        with _span("serve.normalize"):
+            readings = np.asarray(readings, np.float32)
+            if readings.shape != (self.n_streams, self.n_features):
+                raise ValueError(
+                    f"expected ({self.n_streams}, {self.n_features}) "
+                    f"readings, got {readings.shape}")
+            self._pending.append((readings - self._mean) / self._std)
+            # stride > window: readings older than the last `max_window`
+            # can never land in any ring, so drop them HERE — host memory,
+            # host->device transfer and the compiled block shapes all stay
+            # capped at the window.
+            if len(self._pending) > self.max_window:
+                del self._pending[:len(self._pending) - self.max_window]
         self._count += 1
         self.stats.cycles += 1
 
         ready = [(gi, st) for gi, st in enumerate(self._units)
                  if self._ready(st, self._count)]
         if not ready:
-            self.stats.wall_s += time.perf_counter() - t0
             return []
 
         # Async: harvest BEFORE dispatching — the harvested step's calib
@@ -1238,44 +1332,7 @@ class ServingCore:
             key, unpack = list(mega_key), pack.unpack
             self.stats.dispatches += 1
         else:
-            key, rings, calibs, countss, blocks, poss, thrs = \
-                [], [], [], [], [], [], []
-            for gi, st in ready:
-                # span = cycles elapsed since the unit's last fired step;
-                # the pruned pending tail holds at least the last
-                # min(span, window) readings.
-                span = self._count - st.consumed
-                length = min(span, st.window)
-                block = np.stack(self._pending[-length:], axis=1)  # (S,L,F)
-                block = block[st.offset:st.offset + st.n_streams]
-                if st.s_pad != st.n_streams:
-                    block = np.pad(
-                        block,
-                        ((0, st.s_pad - st.n_streams), (0, 0), (0, 0)))
-                # The ring write always ends at (pos + span - 1) mod window;
-                # host-side trimming of long spans shifts the start to
-                # match.
-                eff_pos = (st.pos + (span - length)) % st.window
-                key.append((gi, length))
-                rings.append(self._rings[gi])
-                calibs.append(self._calibs[gi])
-                countss.append(self._counts[gi])
-                blocks.append(self._place(block))
-                poss.append(jnp.int32(eff_pos))
-                thrs.append(self._thr(st))
-                st.pos = (st.pos + span) % st.window
-                st.consumed = self._count
-                st.fires += 1
-
-            new_rings, new_calibs, new_counts, outs = \
-                self._get_step(tuple(key))(
-                    tuple(rings), tuple(calibs), tuple(countss),
-                    tuple(blocks), tuple(poss), tuple(thrs))
-            for (gi, _), ring, calib, counts in zip(key, new_rings,
-                                                    new_calibs, new_counts):
-                self._rings[gi] = ring
-                self._calibs[gi] = calib
-                self._counts[gi] = counts
+            key, outs = self._dispatch_pergroup(ready)
             unpack = _unpack_pergroup
             self.stats.dispatches += sum(
                 self._units[gi].dispatch_cost for gi, _ in key)
@@ -1289,7 +1346,6 @@ class ServingCore:
             self._inflight = flight
         else:
             verdicts = self._finalize(flight)
-        self.stats.wall_s += time.perf_counter() - t0
         return verdicts
 
     def _harvest(self) -> List[Verdict]:
@@ -1303,47 +1359,59 @@ class ServingCore:
         between the sync path (called right after dispatch) and the async
         path (called at the next boundary / flush), so verdict content is
         bit-identical across modes."""
-        outs = flight.unpack(jax.block_until_ready(flight.outs))
-        latency = time.perf_counter() - flight.t0
-        miss = latency > self.deadline_s
-        verdicts: List[Verdict] = []
-        for (gi, _), out in zip(flight.key, outs):
-            st = self._units[gi]
-            # Gathers each device's shard of outputs to the host (the mega
-            # unpack also slices each slot's true payload width); pad-stream
-            # rows are dropped here and never surface as verdicts.
-            out = out[:st.n_streams]
-            self.last_outputs[st.name] = out
-            # Streaming recalibration: re-host the offline score-then-
-            # quantile sequence on the rolling state (pad rows sliced off —
-            # zero streams still score, so they must stay out of the pool).
-            # In async mode this runs before the NEXT dispatch, so the
-            # state read here is exactly this step's output.
-            if st.adapt is not None and st.fires % st.adapt.every == 0:
-                thr = st.head.streaming_threshold(
-                    np.asarray(self._calibs[gi])[:st.n_streams],
-                    np.asarray(self._counts[gi])[:st.n_streams],
-                    min_count=st.adapt.min_count)
-                if thr is not None:
-                    st.live_threshold = thr
-            # Host epilogue via the head: classifier -> argmax/softmax,
-            # score heads -> score vs the unit's LIVE threshold (the
-            # offline cutoff unless adaptation has moved it).
-            pred, prob, score, thr = st.head.host_verdicts(
-                out, threshold=st.live_threshold)
-            for i in range(st.n_streams):
-                verdicts.append(Verdict(
-                    stream=st.offset + i, cycle=flight.cycle,
-                    pred=int(pred[i]),
-                    prob=None if prob is None else float(prob[i]),
-                    latency_s=latency, deadline_miss=miss,
-                    score=None if score is None else float(score[i]),
-                    threshold=thr, group=st.name))
-            st.windows += st.n_streams
-            self.stats.windows += st.n_streams
-            self.stats.deadline_misses += int(miss) * st.n_streams
-        self.stats.latencies_s.append(latency)
-        return verdicts
+        with _span("serve.finalize", cycle=flight.cycle):
+            with _span("serve.block"):
+                jax.block_until_ready(flight.outs)
+            outs = flight.outs if isinstance(flight.outs, tuple) \
+                else (flight.outs,)
+            d2h = sum(math.prod(a.shape) * a.dtype.itemsize for a in outs)
+            self.stats.d2h_bytes += d2h
+            with _span("serve.unpack", d2h_bytes=d2h):
+                outs = flight.unpack(flight.outs)
+            latency = time.perf_counter() - flight.t0
+            miss = latency > self.deadline_s
+            verdicts: List[Verdict] = []
+            for (gi, _), out in zip(flight.key, outs):
+                st = self._units[gi]
+                # Gathers each device's shard of outputs to the host (the
+                # mega unpack also slices each slot's true payload width);
+                # pad-stream rows are dropped here and never surface as
+                # verdicts.
+                out = out[:st.n_streams]
+                self.last_outputs[st.name] = out
+                # Streaming recalibration: re-host the offline score-then-
+                # quantile sequence on the rolling state (pad rows sliced
+                # off — zero streams still score, so they must stay out of
+                # the pool).  In async mode this runs before the NEXT
+                # dispatch, so the state read here is exactly this step's
+                # output.
+                if st.adapt is not None and st.fires % st.adapt.every == 0:
+                    thr = st.head.streaming_threshold(
+                        np.asarray(self._calibs[gi])[:st.n_streams],
+                        np.asarray(self._counts[gi])[:st.n_streams],
+                        min_count=st.adapt.min_count)
+                    if thr is not None:
+                        st.live_threshold = thr
+                # Host epilogue via the head: classifier -> argmax/softmax,
+                # score heads -> score vs the unit's LIVE threshold (the
+                # offline cutoff unless adaptation has moved it).
+                with _span("serve.head"):
+                    pred, prob, score, thr = st.head.host_verdicts(
+                        out, threshold=st.live_threshold)
+                with _span("serve.rows"):
+                    for i in range(st.n_streams):
+                        verdicts.append(Verdict(
+                            stream=st.offset + i, cycle=flight.cycle,
+                            pred=int(pred[i]),
+                            prob=None if prob is None else float(prob[i]),
+                            latency_s=latency, deadline_miss=miss,
+                            score=None if score is None else float(score[i]),
+                            threshold=thr, group=st.name))
+                    st.windows += st.n_streams
+                    self.stats.windows += st.n_streams
+                    self.stats.deadline_misses += int(miss) * st.n_streams
+            self.stats.latencies_s.append(latency)
+            return verdicts
 
     def flush(self) -> List[Verdict]:
         """Drain the in-flight verdict step (``async_depth=1``); returns
