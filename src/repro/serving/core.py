@@ -144,7 +144,7 @@ _SCALAR_OPERAND_BYTES = 4 + 4
 MODEL_SHARD_MIN_WIDTH = 64
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)
 class Verdict:
     """One per-stream verdict on a completed window.
 
@@ -1398,15 +1398,22 @@ class ServingCore:
                 with _span("serve.head"):
                     pred, prob, score, thr = st.head.host_verdicts(
                         out, threshold=st.live_threshold)
+                # One C-level map over whole columns builds the unit's rows,
+                # positionally in Verdict field order; tolist() yields
+                # Python ints and floats.
                 with _span("serve.rows"):
-                    for i in range(st.n_streams):
-                        verdicts.append(Verdict(
-                            stream=st.offset + i, cycle=flight.cycle,
-                            pred=int(pred[i]),
-                            prob=None if prob is None else float(prob[i]),
-                            latency_s=latency, deadline_miss=miss,
-                            score=None if score is None else float(score[i]),
-                            threshold=thr, group=st.name))
+                    n = st.n_streams
+                    verdicts.extend(map(
+                        Verdict, range(st.offset, st.offset + n),
+                        itertools.repeat(flight.cycle, n), pred.tolist(),
+                        itertools.repeat(None, n) if prob is None
+                        else prob.tolist(),
+                        itertools.repeat(latency, n),
+                        itertools.repeat(miss, n),
+                        itertools.repeat(None, n) if score is None
+                        else score.tolist(),
+                        itertools.repeat(thr, n),
+                        itertools.repeat(st.name, n)))
                     st.windows += st.n_streams
                     self.stats.windows += st.n_streams
                     self.stats.deadline_misses += int(miss) * st.n_streams
