@@ -32,6 +32,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from bench import reference as R
+from bench import reference_real as RR
 from bench import trace as T
 from bench import traffic as TR
 from bench import weights as WT
@@ -51,6 +52,12 @@ SAMPLE_WINDOWS = 65536
 MIN_STEPS = 8
 # Verdict steps after the ring fill, before the window opens.
 SETTLE_STEPS = 2
+# Per arithmetic of a configuration (its ``scheme``): the Dense parameters
+# the engine is handed, and the reference's group class.
+SCHEMES = {
+    "SINT": (("qw", "w_scale", "x_scale", "b"), R.GroupReference),
+    "REAL": (("w", "b"), RR.GroupReference),
+}
 
 
 # ---------------------------------------------------------------------------
@@ -87,6 +94,17 @@ def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def scheme(config: dict) -> tuple:
+    """``(parameter keys, reference group class)`` of the configuration's
+    arithmetic; a scheme the benchmark has no reference for raises."""
+    try:
+        return SCHEMES[config["scheme"]]
+    except KeyError:
+        raise ValueError(f"config {config.get('name')!r}: scheme "
+                         f"{config.get('scheme')!r} is not one of "
+                         f"{sorted(SCHEMES)}") from None
+
+
 def load_cell(root: str, name: str) -> Cell:
     """The cell ``name`` of ``<root>/BENCHMARK.json``, with its
     configuration, traffic mix and the metrics it reports."""
@@ -94,6 +112,7 @@ def load_cell(root: str, name: str) -> Cell:
     w = _by_name(bench["workloads"], name, "workload")
     c = _by_name(bench["configs"], w["config"], "config")
     config = _json(os.path.join(root, c["file"]))
+    scheme(config)
     mix = TR.validate(_json(os.path.join(root, "bench", "traffic",
                                          w["traffic"] + ".json")),
                       w["traffic"])
@@ -177,7 +196,9 @@ class CompileCounter:
 def calibration_windows(config: dict, group: dict, pool: np.ndarray,
                         plants: slice) -> np.ndarray:
     """The group's SINT calibration windows: the first complete window of
-    evenly spaced plants of the group, as its model sees them."""
+    evenly spaced plants of the group, as its model sees them.  They are
+    drawn under every scheme, so a seed gives a REAL fleet the very float
+    weights that a SINT fleet quantizes."""
     k = CALIBRATION_WINDOWS
     win = R.windows(pool, config, int(config["window"]) - 1, plants)
     idx = np.linspace(0, len(win) - 1, min(k, len(win))).astype(int)
@@ -187,7 +208,8 @@ def calibration_windows(config: dict, group: dict, pool: np.ndarray,
 def score_thresholds(config: dict, host_layers, pool: np.ndarray) -> list:
     """Per group, the threshold a score head flags about
     ``1 - THRESHOLD_QUANTILE`` of the first windows at (None for a
-    classifier), from the reference."""
+    classifier), from the reference of the configuration's scheme."""
+    _, reference = scheme(config)
     win = R.windows(pool, config, int(config["window"]) - 1)
     out = []
     for g, sl, layers in zip(config["groups"],
@@ -196,15 +218,15 @@ def score_thresholds(config: dict, host_layers, pool: np.ndarray) -> list:
         if g["head"] == "classifier":
             out.append(None)
             continue
-        ref = R.GroupReference(g, config, layers)
-        _, score = ref(win[sl])
+        _, score = reference(g, config, layers)(win[sl])
         out.append(R.thresholds(score, THRESHOLD_QUANTILE))
     return out
 
 
 def build_engine(config: dict, plants: int, device_layers, thresholds):
     """The system under test, built from the configuration with the
-    program's own defaults."""
+    program's own defaults, handed the Dense parameters of its scheme."""
+    keys, _ = scheme(config)
     from repro.core.layers import Dense, Input
     from repro.core.model import sequential
     from repro.serving import GroupedStreamEngine, ModelGroup, StreamEngine
@@ -222,8 +244,7 @@ def build_engine(config: dict, plants: int, device_layers, thresholds):
         for node in model.graph.nodes:
             if isinstance(node.layer, Dense):
                 p = next(it)
-                params[node.uid] = {k: p[k] for k in
-                                    ("qw", "w_scale", "x_scale", "b")}
+                params[node.uid] = {k: p[k] for k in keys}
             else:
                 params[node.uid] = {}
         head = {"classifier": lambda: None,
@@ -517,13 +538,14 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     # For the readings of the control, which compare the reference at a
     # lower precision on the same windows; never printed.
     result["_state"] = {"pool": pool, "host_layers": host_layers,
-                        "thresholds": thresholds, "cycles": sorted(steps)}
+                        "thresholds": thresholds, "steps": steps}
     return result
 
 
-def references(config: dict, host_layers, thresholds,
-               qmax: int = R.SINT_QMAX) -> list:
-    return [R.GroupReference(g, config, layers, threshold=thr, qmax=qmax)
+def references(config: dict, host_layers, thresholds) -> list:
+    """Per group, the reference of the configuration's scheme."""
+    _, reference = scheme(config)
+    return [reference(g, config, layers, threshold=thr)
             for g, layers, thr in zip(config["groups"], host_layers,
                                       thresholds)]
 

@@ -6,8 +6,9 @@ For each seed it makes one run of the cell as ``bench/run.py`` does (a
 short window at the cell's own load) and prints the numbers compared.
 Then it puts the control in the program's place: the reference computed at
 the precision below the one the configuration states (int4 for SINT's
-int8), over the same sampled windows, compared with the reference as the
-program is.  The lower reading of a number is the largest the program
+int8; for REAL's float32 one bfloat16 pass, the chip's default f32 dot),
+over the same sampled windows, compared with the reference as the program
+is.  The lower reading of a number is the largest the program
 gives; the upper, the smallest the control gives.  The benchmark's own runs
 never run the control.
 """
@@ -17,28 +18,59 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import json  # noqa: E402
 
 
-def control_tally(cell, state: dict, qmax: int):
-    """The control's numbers: the reference at ``qmax`` in the program's
-    place, over the windows of the run's sampled steps."""
+def controls(config: dict, host_layers, thresholds) -> list:
+    """Per group, the reference one precision below the configuration's."""
+    from bench import harness
+    from bench import reference as R
+    from bench import reference_real as RR
+    harness.scheme(config)
+    make = {"SINT": functools.partial(R.GroupReference, qmax=R.CONTROL_QMAX),
+            "REAL": functools.partial(RR.GroupReference, one_pass=True),
+            }[config["scheme"]]
+    return [make(g, config, layers, threshold=thr)
+            for g, layers, thr in zip(config["groups"], host_layers,
+                                      thresholds)]
+
+
+def group_tallies(cell, state: dict) -> list:
+    """Per group, ``(name, program tally, control tally)`` over the run's
+    sampled steps: which head sets each reading."""
     from bench import harness
     from bench import reference as R
     config, pool = cell.config, state["pool"]
     refs = harness.references(config, state["host_layers"],
                               state["thresholds"])
-    lower = harness.references(config, state["host_layers"],
-                               state["thresholds"], qmax=qmax)
-    tally = R.Tally()
+    lower = controls(config, state["host_layers"], state["thresholds"])
+    out = [(ref.group["name"], R.Tally(), R.Tally()) for ref in refs]
     slices = R.group_slices(config, pool.shape[1])
-    for cycle in state["cycles"]:
+    for cycle, (pred, tail) in sorted(state["steps"].items()):
         win = R.windows(pool, config, cycle)
-        for sl, ref, low in zip(slices, refs, lower):
-            pred, tail = low(win[sl])
-            tally.add(f"cycle {cycle} group {ref.group['name']}", pred, tail,
-                      ref, win[sl])
-    return tally
+        for sl, ref, low, (name, prog, ctrl) in zip(slices, refs, lower, out):
+            label = f"cycle {cycle} group {name}"
+            prog.add(label, pred[sl], tail[sl], ref, win[sl])
+            ctrl.add(label, *low(win[sl]), ref, win[sl])
+    return out
+
+
+def total(tallies):
+    """One tally of the worst readings over ``tallies``."""
+    from bench import reference as R
+    out = R.Tally()
+    for t in tallies:
+        out.windows += t.windows
+        out.pred_off += t.pred_off
+        out.tail_rel_err = max(out.tail_rel_err, t.tail_rel_err)
+    return out
+
+
+def control_tally(cell, state: dict):
+    """The control's numbers over every group: the control in the
+    program's place, over the windows of the run's sampled steps."""
+    return total(c for _, _, c in group_tallies(cell, state))
 
 
 def main(argv=None) -> None:
@@ -50,20 +82,23 @@ def main(argv=None) -> None:
     import run  # bench/run.py, beside this script
     run.use_checkout()
     from bench import harness
-    from bench import reference as R
     cell = harness.load_cell(run.ROOT, args.workload)
     t0 = T_PROCESS
     for seed in args.seeds:
         result = harness.run(cell, seed, args.seconds, False, t0)
         state = result.pop("_state")
         diag = result.pop("_diagnostics")
-        ctrl = control_tally(cell, state, R.CONTROL_QMAX)
+        groups = group_tallies(cell, state)
+        ctrl = total(c for _, _, c in groups)
         print(json.dumps({
             "seed": seed, "correct": result["correct"],
             "program": {k: v["value"] for k, v in result["checks"].items()},
             "control": {"pred_off": ctrl.pred_off,
                         "tail_rel_err": ctrl.tail_rel_err,
                         "windows": ctrl.windows},
+            "by_group": {name: {"program": [p.pred_off, p.tail_rel_err],
+                                "control": [c.pred_off, c.tail_rel_err]}
+                         for name, p, c in groups},
             "diagnostics": {k: diag[k] for k in (
                 "steps", "compared_windows", "windows_with_near_ties",
                 "near_ties_excused", "borderline_flips", "rel_over",

@@ -1,5 +1,6 @@
-"""The plain reference of every configuration in ``bench/configs/``, and the
-comparison that decides ``correct``.
+"""The plain reference of every SINT configuration in ``bench/configs/``
+(REAL's is ``bench/reference_real.py``), and the comparison that decides
+``correct``.
 
 It imports nothing of the program.  From the pool of raw readings it builds
 each verdict's window itself (normalization, then the last ``window``

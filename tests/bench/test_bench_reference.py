@@ -12,6 +12,7 @@ import pytest
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 from bench import reference as R  # noqa: E402
+from bench import reference_real as RR  # noqa: E402
 from bench import traffic as TR  # noqa: E402
 
 
@@ -156,3 +157,72 @@ def test_control_quantizes_at_int4():
     low = R.requantize_layers(layers, R.CONTROL_QMAX)[0]
     assert np.abs(low["qw"]).max() == 7
     assert low["x_scale"] == np.float32(2.0 / 7)
+
+
+def test_bf16_rounds_to_nearest_even_as_jax_does():
+    import jax.numpy as jnp
+    rng = np.random.default_rng(5)
+    x = np.concatenate([rng.normal(size=4096).astype(np.float32) * 100,
+                        np.float32([0.0, -0.0, 1.0, 1 + 2**-8, 1 + 3 * 2**-8,
+                                    -(1 + 2**-8), 3e-39])])
+    want = np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
+    assert np.array_equal(RR.bf16(x), want)
+
+
+def real_layers():
+    rng = np.random.default_rng(7)
+    return [{"w": rng.normal(size=(6, 5)).astype(np.float32),
+             "b": rng.normal(size=5).astype(np.float32)},
+            {"w": rng.normal(size=(5, 3)).astype(np.float32),
+             "b": rng.normal(size=3).astype(np.float32)}]
+
+
+def test_real_stack_is_the_f32_answer_rounded_once():
+    x = np.random.default_rng(8).normal(size=(40, 6)).astype(np.float32)
+    layers, acts = real_layers(), ["relu", "linear"]
+    h = np.float64(x) @ np.float64(layers[0]["w"])
+    h = np.maximum(np.float32(h) + layers[0]["b"], np.float32(0))
+    y = np.float32(np.float64(h) @ np.float64(layers[1]["w"])) + layers[1]["b"]
+    assert np.array_equal(RR.mlp(x, layers, acts), y)
+    low = RR.mlp(x, layers, acts, one_pass=True)
+    assert not np.array_equal(low, y)
+    assert np.allclose(low, y, rtol=0.05, atol=0.05)
+
+
+def test_real_reference_records_no_near_ties():
+    group = {"name": "t", "head": "classifier", "widths": [6, 5, 3],
+             "activations": ["relu", "linear"]}
+    ref = RR.GroupReference(group, {"n_features": 2}, real_layers())
+    ties = []
+    # Inputs on requantize half-integers would be near-ties under SINT.
+    ref.outputs(np.full((4, 6), 2.5, np.float32), ties=ties)
+    assert ties == []
+
+
+def test_real_reference_matches_the_program_oracle_path():
+    """``GroupedStreamEngine`` on the CPU, packed into the megakernel with
+    REAL parameters, 4 x 16 plants of the four heads, against the f32
+    reference on seeded weights."""
+    from bench import harness as H
+    from bench import weights as WT
+    cfg, plants, seed = config("msf_mixed4_real"), 64, 2**31 + 5
+    p = TR.pool(TR.validate({"plants": plants}), cfg, seed)
+    calibs = [H.calibration_windows(cfg, g, p, sl) for g, sl in
+              zip(cfg["groups"], R.group_slices(cfg, plants))]
+    device = WT.make(cfg, seed, calibs)
+    host = WT.to_host(device)
+    thr = H.score_thresholds(cfg, host, p)
+    engine = H.build_engine(cfg, plants, device, thr)
+    assert engine.mega_reason is None
+    steps = {}
+    for c in range(int(cfg["window"]) + 4 * int(cfg["stride"])):
+        verdicts = engine.ingest(p[c])
+        if verdicts:
+            pred, tail, ok = H.step_arrays(verdicts, c, plants)
+            assert ok.all()
+            steps[c] = (pred, tail)
+    assert len(steps) == 5
+    tally = R.compare_steps(cfg, p, H.references(cfg, host, thr), steps)
+    assert tally.windows == 5 * plants
+    assert tally.pred_off == 0 and tally.near_ties == 0
+    assert tally.tail_rel_err <= cfg["tail_rel_err"]

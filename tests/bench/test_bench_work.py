@@ -66,3 +66,24 @@ def test_peak_table_names_its_source_and_raises_for_an_unknown_kind():
     assert "TPU v5e" in row["source"]
     with pytest.raises(ValueError, match="not in the peak table"):
         work.peaks("cpu")
+
+
+def test_grouped_fused_mlp_work_by_hand_under_real():
+    # The REAL fleet: four-byte weights, biases, no scales; the same
+    # operations, held to the bf16 peak (an upper bound on f32 at HIGHEST).
+    cfg = config("msf_mixed4_real")
+    m = 1024
+    weights = 400 * 64 + 64 * 32 + 32 * 16 + 16 * 2 \
+        + 400 * 64 + 64 * 16 + 16 * 64 + 64 * 400 \
+        + 400 * 64 + 64 * 32 + 32 * 16 + 398 * 64 + 64 * 32 + 32 * 2
+    assert weights == 137184
+    biases = (64 + 32 + 16 + 2) + (64 + 16 + 64 + 400) + (64 + 32 + 16) \
+        + (64 + 32 + 2)
+    ops, nbytes = work.grouped_fused_mlp(cfg, m)
+    assert ops == 2 * m * weights
+    assert nbytes == (4 * m * 400 * 4 + weights * 4 + biases * 4
+                      + m * (2 + 1 + 1 + 1) * 4)
+    row = work.peaks("TPU v5 lite")
+    assert work.peak_ops(row, "REAL") == 197e12
+    assert work.roofline_s(ops, nbytes, row, "REAL") == pytest.approx(
+        nbytes / 819e9)
