@@ -12,7 +12,8 @@ error and the last key of the result.
 
 The harness consumes ``ingest()``'s return as a sequence of rows with
 ``stream``, ``cycle``, ``pred`` and ``prob`` (classifier) or ``score``
-(score heads).
+(score heads), and, where the configuration sets ``adapt``, each score
+head's ``threshold``.
 """
 
 from __future__ import annotations
@@ -32,6 +33,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from bench import reference as R
+from bench import reference_adapt as RA
 from bench import reference_real as RR
 from bench import trace as T
 from bench import traffic as TR
@@ -44,8 +46,10 @@ CACHE_DIR = os.path.join(".bench_cache", "jax")
 TRACE_DIR = os.path.join(".bench_cache", "trace")
 # SINT activation scales come from this many benign windows of the traffic.
 CALIBRATION_WINDOWS = 16
-# A score head's threshold flags about a tenth of the first windows.
+# A score head's threshold flags about a tenth of the first windows; an
+# adapting head recalibrates to the same false-positive rate.
 THRESHOLD_QUANTILE = 0.9
+TARGET_FPR = 1 - THRESHOLD_QUANTILE
 # The comparison covers at least this many windows, in at least MIN_STEPS
 # sampled verdict steps.
 SAMPLE_WINDOWS = 65536
@@ -113,6 +117,7 @@ def load_cell(root: str, name: str) -> Cell:
     c = _by_name(bench["configs"], w["config"], "config")
     config = _json(os.path.join(root, c["file"]))
     scheme(config)
+    RA.policy(config)
     mix = TR.validate(_json(os.path.join(root, "bench", "traffic",
                                          w["traffic"] + ".json")),
                       w["traffic"])
@@ -225,11 +230,14 @@ def score_thresholds(config: dict, host_layers, pool: np.ndarray) -> list:
 
 def build_engine(config: dict, plants: int, device_layers, thresholds):
     """The system under test, built from the configuration with the
-    program's own defaults, handed the Dense parameters of its scheme."""
+    program's own defaults, handed the Dense parameters of its scheme; its
+    score heads adapt where the configuration sets ``adapt``."""
     keys, _ = scheme(config)
+    adapt = RA.policy(config)
     from repro.core.layers import Dense, Input
     from repro.core.model import sequential
-    from repro.serving import GroupedStreamEngine, ModelGroup, StreamEngine
+    from repro.serving import (AdaptConfig, GroupedStreamEngine, ModelGroup,
+                               StreamEngine)
     from repro.sim.heads import ForecastHead, MarginHead, ReconstructionHead
 
     per = plants // len(config["groups"])
@@ -247,26 +255,30 @@ def build_engine(config: dict, plants: int, device_layers, thresholds):
                 params[node.uid] = {k: p[k] for k in keys}
             else:
                 params[node.uid] = {}
+        score = dict(threshold=thr, target_fpr=TARGET_FPR)
         head = {"classifier": lambda: None,
-                "reconstruction": lambda: ReconstructionHead(threshold=thr),
+                "reconstruction": lambda: ReconstructionHead(**score),
                 "margin": lambda: MarginHead(
-                    threshold=thr, center=(0.0,) * int(widths[-1])),
+                    center=(0.0,) * int(widths[-1]), **score),
                 "forecast": lambda: ForecastHead(
-                    threshold=thr, n_features=int(config["n_features"])),
+                    n_features=int(config["n_features"]), **score),
                 }[g["head"]]()
-        units.append((g["name"], model, params, head))
+        unit_adapt = (None if adapt is None or head is None
+                      else AdaptConfig(**adapt))
+        units.append((g["name"], model, params, head, unit_adapt))
     common = dict(n_features=int(config["n_features"]),
                   stride=int(config["stride"]),
                   deadline_s=float(config["deadline_s"]),
                   norm_mean=tuple(config["norm_mean"]),
                   norm_std=tuple(config["norm_std"]), async_depth=0)
     if config["engine"] == "stream":
-        (_, model, params, head), = units
+        (_, model, params, head, unit_adapt), = units
         return StreamEngine(model, params, n_streams=plants, head=head,
-                            **common)
+                            adapt=unit_adapt, **common)
     if config["engine"] == "grouped":
         return GroupedStreamEngine(
-            [ModelGroup(n, m, p, per, h) for n, m, p, h in units], **common)
+            [ModelGroup(n, m, p, per, h, adapt=a)
+             for n, m, p, h, a in units], **common)
     raise ValueError(f"unknown engine {config['engine']!r}")
 
 
@@ -372,6 +384,28 @@ def step_arrays(verdicts, cycle: int, plants: int):
     return pred, tail, seen & ~dup
 
 
+def step_thresholds(verdicts, cycle: int, config: dict, plants: int):
+    """Each group's threshold as its rows report it in one sampled step
+    (None for a classifier), the value most of the group's rows give; and
+    the plants whose row gives another, or none."""
+    thr = np.full(plants, np.nan)
+    for v in verdicts:
+        i = int(v.stream)
+        if v.cycle == cycle and 0 <= i < plants and v.threshold is not None:
+            thr[i] = float(v.threshold)
+    off = np.zeros(plants, bool)
+    out = []
+    for g, sl in zip(config["groups"], R.group_slices(config, plants)):
+        if g["head"] == "classifier":
+            out.append(None)
+            continue
+        values, counts = np.unique(thr[sl], return_counts=True)
+        top = values[np.argmax(counts)]
+        out.append(float(top))
+        off[sl] = thr[sl] != top
+    return out, off
+
+
 # ---------------------------------------------------------------------------
 # A run
 
@@ -464,26 +498,47 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     gc.collect()
 
     t_check = time.perf_counter()
+    adapt = RA.policy(config)
     failed = win.missing
     steps: Dict[int, tuple] = {}
+    reported: Dict[int, list] = {}
     for cycle, verdicts in sampler.kept:
         # A sampled step's due verdicts are checked one by one: each that
         # did not come back once, for its plant and cycle, has failed.
         pred, tail, ok = step_arrays(verdicts, cycle, plants)
         failed += int((~ok).sum()) - max(0, plants - len(verdicts))
+        if adapt is not None:
+            # So has each whose threshold is not its unit's.
+            thr, off = step_thresholds(verdicts, cycle, config, plants)
+            failed += int((off & ok).sum())
+            ok &= ~off
         if ok.all():
             steps[cycle] = (pred, tail)
+            if adapt is not None:
+                reported[cycle] = thr
     limit = float(config["tail_rel_err"])
-    tally = R.compare_steps(config, pool, references(config, host_layers,
-                                                     thresholds), steps)
+    refs = references(config, host_layers, thresholds)
+    if adapt is None:
+        tally = R.compare_steps(config, pool, refs, steps)
+    else:
+        step_thr, pools = RA.replay(
+            config, pool, refs, thresholds, TARGET_FPR,
+            max(steps, default=int(config["window"]) - 1), keep=set(steps))
+        tally, band = RA.compare_steps(config, pool, refs, steps, step_thr,
+                                       reported)
+    # JSON has no infinity: a NaN or infinite answer reads as the largest
+    # float.
     checks = {
         "failed": {"value": failed, "limit": 0},
         "pred_off": {"value": tally.pred_off, "limit": 0},
-        # JSON has no infinity: a NaN or infinite answer reads as the
-        # largest float.
         "tail_rel_err": {"value": min(tally.tail_rel_err, sys.float_info.max),
                          "limit": limit},
     }
+    if adapt is not None:
+        checks["thr_rel_err"] = {
+            "value": min(RA.thr_rel_err(reported, step_thr),
+                         sys.float_info.max),
+            "limit": float(config["thr_rel_err"])}
     correct = (all(v["value"] <= v["limit"] for v in checks.values())
                and tally.windows > 0)
     check_s = time.perf_counter() - t_check
@@ -539,6 +594,15 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     # lower precision on the same windows; never printed.
     result["_state"] = {"pool": pool, "host_layers": host_layers,
                         "thresholds": thresholds, "steps": steps}
+    if adapt is not None:
+        last = max(steps, default=None)
+        result["_diagnostics"].update(
+            live_thresholds=None if last is None else
+            {"program": reported[last], "reference": step_thr[last]},
+            thr_rank_gap=RA.rank_gap(reported, step_thr, pools),
+            threshold_band_flips=band)
+        result["_state"].update(step_thresholds=step_thr, reported=reported,
+                                pools=pools)
     return result
 
 

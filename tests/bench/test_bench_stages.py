@@ -264,3 +264,26 @@ def test_recorded_idle_split_by_stage(chip):
     # Outside every stage the split is the harness's own, less the stages.
     by_span = T.idle_by_span(ops, chip["spans"], lo, hi)
     assert by["harness"] == by_span["harness"]
+
+
+def test_finalize_reader_by_hand_and_without_stages(hand, old, monkeypatch,
+                                                    tmp_path):
+    """``stage_ms.finalize``: the self time of ``serve.finalize`` per
+    verdict step; nothing where the trace has no stages."""
+    assert read("stage_ms.finalize", ctx_of(hand)) == (3 + 2) / 2 / 1e6
+    monkeypatch.setattr(S, "ROOT", str(tmp_path))
+    assert read("stage_ms.finalize", ctx_of(old)) is None
+    untraced = types.SimpleNamespace(trace=None, lo=0, hi=0)
+    assert read("stage_ms.finalize", untraced) is None
+
+
+def test_finalize_reader_on_the_recorded_trace(chip):
+    """Outside its block, unpack, head and rows stages the recorded
+    non-adapting step's harvest takes 34 us."""
+    ctx = ctx_of(chip)
+    assert read("stage_ms.finalize", ctx) == 67460 / 2 / 1e6
+    lo, hi = T.window(chip)
+    total = S.total_ns(chip["stages"], lo, hi)
+    assert 67460 == total["serve.finalize"] - sum(
+        total[n] for n in ("serve.block", "serve.unpack", "serve.head",
+                           "serve.rows"))
