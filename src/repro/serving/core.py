@@ -103,11 +103,14 @@ operands the step hands the device) and ``serve.finalize`` (carrying the
 ``cycle`` of the step it finalizes, so a step's spans share one
 identifier in sync and async mode), which holds ``serve.block``,
 ``serve.unpack`` (carrying ``d2h_bytes``, the outputs copied back) and,
-per unit, ``serve.head`` and ``serve.rows``.  A step has a fixed number
-of spans, none per plant; with no trace active each costs well under a
-microsecond.  On the device, each step's ring write and window gather run
-under the named scope ``ring_scatter``.  ``StreamStats.h2d_bytes`` /
-``d2h_bytes`` sum the same byte counts.
+per unit, ``serve.head`` and ``serve.rows``; ``serve.finalize`` also
+carries ``recalibrations`` and ``calib_d2h_bytes``, the adapting units it
+recalibrates and the bytes of calibration state they read.  A step has a
+fixed number of spans, none per plant; with no trace active each costs
+well under a microsecond.  On the device, each step's ring write and
+window gather run under the named scope ``ring_scatter``.
+``StreamStats.h2d_bytes`` / ``d2h_bytes`` / ``recalibrations`` /
+``calib_d2h_bytes`` sum the same counts.
 """
 
 from __future__ import annotations
@@ -278,7 +281,13 @@ class StreamStats:
     ``h2d_bytes`` counts the host arrays and scalars each verdict step
     hands the device (pending blocks, write positions, thresholds);
     ``d2h_bytes`` the step outputs copied back at finalize.  Both are exact
-    and repeat exactly for a given fleet."""
+    and repeat exactly for a given fleet.
+
+    ``recalibrations`` counts the streaming threshold recalibrations run
+    (one per adapting unit per ``AdaptConfig.every`` of its fired steps),
+    and ``calib_d2h_bytes`` the calibration rings and admission counts
+    copied to the host for them (pad rows included, as copied);
+    ``d2h_bytes`` leaves that state out."""
 
     steps: int                       # jitted detector steps executed
     cycles: int                      # scan cycles ingested
@@ -288,6 +297,8 @@ class StreamStats:
     dispatches: int = 0              # logical kernel dispatches issued
     h2d_bytes: int = 0               # host->device bytes of step operands
     d2h_bytes: int = 0               # device->host bytes of step outputs
+    recalibrations: int = 0          # unit threshold recalibrations run
+    calib_d2h_bytes: int = 0         # device->host bytes of calib state
     latencies_s: LatencyReservoir = dataclasses.field(
         default_factory=LatencyReservoir)
 
@@ -1137,11 +1148,7 @@ class ServingCore:
                 tuple(self._calibs[gi] for gi, _ in key),
                 tuple(self._counts[gi] for gi, _ in key),
                 block, poss, thrs, pack.arrays, pack.centers)
-        for (gi, _), ring, calib, counts in zip(key, new_rings, new_calibs,
-                                                new_counts):
-            self._rings[gi] = ring
-            self._calibs[gi] = calib
-            self._counts[gi] = counts
+        self._adopt(key, new_rings, new_calibs, new_counts)
         return payload, pack
 
     def _dispatch_pergroup(self, ready) -> Tuple[List, Any]:
@@ -1184,12 +1191,29 @@ class ServingCore:
             new_rings, new_calibs, new_counts, outs = step(
                 tuple(rings), tuple(calibs), tuple(countss), tuple(blocks),
                 tuple(poss), tuple(thrs))
-        for (gi, _), ring, calib, counts in zip(key, new_rings, new_calibs,
-                                                new_counts):
+        self._adopt(key, new_rings, new_calibs, new_counts)
+        return key, outs
+
+    @staticmethod
+    def _recalibrates(st: _UnitState) -> bool:
+        """Whether the unit's last fired step recalibrates its threshold
+        when finalized (``fires`` counts at dispatch, and a step is
+        finalized before the next dispatch)."""
+        return st.adapt is not None and st.fires % st.adapt.every == 0
+
+    def _adopt(self, key, rings, calibs, countss) -> None:
+        """Install a fired step's new ring and calibration states, and start
+        the host copy of each state that this step's finalize recalibrates
+        from: the copies queue behind the step on the device and overlap
+        the wait for its outputs, so ``_finalize`` pays no round trip for
+        them."""
+        for (gi, _), ring, calib, counts in zip(key, rings, calibs, countss):
             self._rings[gi] = ring
             self._calibs[gi] = calib
             self._counts[gi] = counts
-        return key, outs
+            if self._recalibrates(self._units[gi]):
+                calib.copy_to_host_async()
+                counts.copy_to_host_async()
 
     def _mega_example_args(self, key: Tuple) -> Tuple[Callable, Tuple]:
         """(step, zeroed operands) for a ready-combination's megakernel
@@ -1359,7 +1383,14 @@ class ServingCore:
         between the sync path (called right after dispatch) and the async
         path (called at the next boundary / flush), so verdict content is
         bit-identical across modes."""
-        with _span("serve.finalize", cycle=flight.cycle):
+        recal = [gi for gi, _ in flight.key
+                 if self._recalibrates(self._units[gi])]
+        calib_d2h = sum(self._calibs[gi].nbytes + self._counts[gi].nbytes
+                        for gi in recal)
+        self.stats.recalibrations += len(recal)
+        self.stats.calib_d2h_bytes += calib_d2h
+        with _span("serve.finalize", cycle=flight.cycle,
+                   recalibrations=len(recal), calib_d2h_bytes=calib_d2h):
             with _span("serve.block"):
                 jax.block_until_ready(flight.outs)
             outs = flight.outs if isinstance(flight.outs, tuple) \
@@ -1382,10 +1413,11 @@ class ServingCore:
                 # Streaming recalibration: re-host the offline score-then-
                 # quantile sequence on the rolling state (pad rows sliced
                 # off — zero streams still score, so they must stay out of
-                # the pool).  In async mode this runs before the NEXT
-                # dispatch, so the state read here is exactly this step's
-                # output.
-                if st.adapt is not None and st.fires % st.adapt.every == 0:
+                # the pool).  The state's host copy started at dispatch
+                # (``_adopt``), so np.asarray here waits on no new round
+                # trip.  In async mode this runs before the NEXT dispatch,
+                # so the state read here is exactly this step's output.
+                if gi in recal:
                     thr = st.head.streaming_threshold(
                         np.asarray(self._calibs[gi])[:st.n_streams],
                         np.asarray(self._counts[gi])[:st.n_streams],

@@ -71,7 +71,11 @@ distribution creeps.  Every :class:`ScoreHead` therefore also owns the
 * :meth:`streaming_threshold` — the **host-side** re-host of
   ``recalibrate_threshold``'s score-then-quantile sequence onto that state:
   :func:`conservative_quantile` of the pooled valid ring scores at the
-  head's recorded ``target_fpr``.
+  head's recorded ``target_fpr``.  It selects that order statistic from
+  the f32 pool with ``np.partition`` at the index ``np.quantile``'s
+  ``method="higher"`` takes, so it returns the same value bit for bit
+  without a float64 copy or a sort (and, once every stream's ring is full,
+  without the valid-slot gather).
 
 The engines keep a *live* threshold (seeded by the offline-calibrated one)
 that tracks the streaming quantile; ``Verdict.threshold`` reports the live
@@ -82,6 +86,7 @@ recalibration chases the same operating point the offline calibration chose.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -105,6 +110,15 @@ def conservative_quantile(scores: np.ndarray, target_fpr: float) -> float:
     even on small calibration sets."""
     return float(np.quantile(np.asarray(scores, np.float64), 1.0 - target_fpr,
                              method="higher"))
+
+
+@functools.lru_cache(maxsize=None)
+def _higher_rank(n: int, q: float) -> int:
+    """The sorted-order index of the value ``np.quantile(scores, q,
+    method="higher")`` returns for ``n`` scores: the same quantile of ``0,
+    1, ..., n - 1``, so the index arithmetic is numpy's own."""
+    return int(np.quantile(np.arange(n, dtype=np.float64), q,
+                           method="higher"))
 
 
 class DetectorHead:
@@ -405,18 +419,32 @@ class ScoreHead(DetectorHead):
                             min_count: int = 1) -> Optional[float]:
         """Host-side re-host of ``recalibrate_threshold``'s score-then-
         quantile sequence onto the streaming state: the conservative
-        ``(1 - target_fpr)`` quantile of the pooled valid ring scores.
-        Returns None (leave the live threshold alone) until ``min_count``
-        scores have been admitted fleet-wide."""
+        ``(1 - target_fpr)`` quantile of the pooled valid ring scores,
+        bit for bit ``conservative_quantile(streaming_scores(ring, counts),
+        target_fpr)``.  That quantile is an order statistic, so it is
+        selected (``np.partition`` of the f32 pool) rather than sorted out
+        of a float64 copy.  Returns None (leave the live threshold alone)
+        until ``min_count`` scores have been admitted fleet-wide."""
         if self.target_fpr is None:
             raise ValueError(
                 f"{type(self).__name__} has no target_fpr; calibrate via "
                 "head.calibrate / the sim.detector trainers (or construct "
                 "with target_fpr=) before streaming recalibration")
-        scores = self.streaming_scores(ring, counts)
-        if scores.size < max(min_count, 1):
+        ring = np.asarray(ring)
+        counts = np.asarray(counts)
+        if counts.size and counts.min() >= ring.shape[1]:
+            pool = ring.ravel()        # every slot holds a score: no gather
+        else:
+            pool = self.streaming_scores(ring, counts)
+        n = pool.size
+        if n < max(min_count, 1):
             return None
-        return conservative_quantile(scores, self.target_fpr)
+        k = _higher_rank(n, 1.0 - self.target_fpr)
+        tail = np.partition(pool, k)[k:]
+        # NaN sorts last, so any NaN in the pool lies in the tail; as in
+        # np.quantile, a pool holding one yields a NaN.
+        nan = np.isnan(tail)
+        return float(tail[nan][-1] if nan.any() else tail[0])
 
 
 @dataclasses.dataclass(frozen=True)

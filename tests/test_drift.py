@@ -158,6 +158,47 @@ class TestStreamingThresholdProperty:
             np.sort(head.streaming_scores(ring, counts)), [5.0, 6.0, 7.0])
         assert head.streaming_threshold(ring, counts) == 7.0
 
+    # Counts of 8 streams at capacity 6: below, at and past it, mixed;
+    # pooled sizes 1, MIN_COUNT - 1, MIN_COUNT and S x capacity.
+    MIN_COUNT = 5
+    MIXED = [0, 3, 6, 9, 6, 1, 12, 5]
+    FULL = [6, 7, 6, 13, 6, 6, 9, 6]
+    SELECT_CASES = {
+        "mixed": MIXED, "pool-1": [0, 0, 1, 0, 0, 0, 0, 0],
+        "pool-min-1": [0, 2, 0, 1, 0, 0, 1, 0],
+        "pool-min": [0, 2, 0, 1, 0, 0, 1, 1], "pool-full": FULL,
+        "ties": MIXED, "ties-full": FULL, "nan": MIXED, "nan-full": FULL}
+
+    @pytest.mark.parametrize("target_fpr", [0.1, 0.05])
+    @pytest.mark.parametrize("case", list(SELECT_CASES))
+    def test_selection_is_the_conservative_quantile_bitwise(self, case,
+                                                            target_fpr):
+        """The selected order statistic is bit for bit the float64
+        ``conservative_quantile`` of the pooled valid scores (None below
+        ``min_count``); a NaN outside the valid slots stays out of the
+        pool, one inside gives what ``np.quantile`` gives."""
+        head = ReconstructionHead(threshold=1.0, target_fpr=target_fpr)
+        rng = np.random.default_rng(len(case))
+        ring = rng.exponential(1.0, size=(8, 6)).astype(np.float32)
+        counts = np.asarray(self.SELECT_CASES[case], np.int32)
+        if case.startswith("ties"):
+            ring = np.round(ring * 2) / 2          # a handful of values
+        short = np.flatnonzero(counts < ring.shape[1])
+        if short.size:                             # an unused slot
+            ring[short[0], counts[short[0]]] = np.nan
+        if case.startswith("nan"):
+            ring[3, 2] = np.nan                    # a pooled slot
+        pool = head.streaming_scores(ring, counts)
+        assert pool.size == np.minimum(counts, ring.shape[1]).sum()
+        assert np.isnan(pool).any() == case.startswith("nan")
+        for min_count in (1, self.MIN_COUNT):
+            got = head.streaming_threshold(ring, counts, min_count=min_count)
+            if pool.size < min_count:
+                assert got is None
+                continue
+            want = conservative_quantile(pool, target_fpr)
+            assert np.float64(got).tobytes() == np.float64(want).tobytes()
+
     def test_requires_target_fpr(self):
         head = ReconstructionHead(threshold=1.0)
         ring, counts = head.calib_state(1, 4)
@@ -335,6 +376,52 @@ class TestGroupedAdaptation:
         assert live["adapt"] == se.live_threshold != 2.0
         assert live["frozen"] == 2.0
         assert all(v.threshold == 2.0 for v in gv if v.group == "frozen")
+
+    @pytest.mark.parametrize("megakernel", [None, False],
+                             ids=["mega", "per-group"])
+    @pytest.mark.parametrize("async_depth", [0, 1], ids=["sync", "async"])
+    @pytest.mark.parametrize("every", [1, 2])
+    def test_recalibration_counters(self, every, async_depth, megakernel):
+        """``StreamStats.recalibrations`` adds one per recalibration the
+        adaptive group runs (every ``every``-th of its finalized steps) and
+        ``calib_d2h_bytes`` its ring and counts each time; a fleet without
+        an adapting unit counts neither, and ``d2h_bytes`` (the payload)
+        reads the same in both."""
+        window, n_feat, n_per, capacity = 8, 2, 3, 4
+        model, params = energy_detector(window, n_feat)
+        cfg = AdaptConfig(capacity=capacity, every=every, min_count=3,
+                          headroom=3.0)
+
+        def build(adapt):
+            return GroupedStreamEngine(
+                [ModelGroup("adapt", model, params, n_per,
+                            ReconstructionHead(threshold=2.0,
+                                               target_fpr=0.2),
+                            adapt=adapt),
+                 ModelGroup("frozen", model, params, n_per,
+                            ReconstructionHead(threshold=2.0))],
+                stride=3, async_depth=async_depth, megakernel=megakernel)
+
+        adaptive, frozen = build(cfg), build(None)
+        s_pad = -(-n_per // adaptive.n_shards) * adaptive.n_shards
+        per = s_pad * capacity * 4 + s_pad * 4
+        readings = fleet_readings(2 * n_per, 40, seed=29)
+        finalized = recals = 0
+        for c in range(41):
+            if c < 40:
+                got = adaptive.ingest(readings[c])
+                frozen.ingest(readings[c])
+            else:
+                got, _ = adaptive.flush(), frozen.flush()
+            if any(v.group == "adapt" for v in got):
+                finalized += 1
+                recals += finalized % every == 0
+            assert adaptive.stats.recalibrations == recals
+            assert adaptive.stats.calib_d2h_bytes == recals * per
+        assert finalized > 2 * every and recals == finalized // every
+        assert frozen.stats.recalibrations == 0
+        assert frozen.stats.calib_d2h_bytes == 0
+        assert adaptive.stats.d2h_bytes == frozen.stats.d2h_bytes > 0
 
     def test_group_adapt_validation(self):
         model, params = energy_detector(4, 1)

@@ -14,7 +14,9 @@ import jax
 import numpy as np
 import pytest
 
-from repro.serving import GroupedStreamEngine, StreamEngine
+from repro.serving import AdaptConfig, GroupedStreamEngine, StreamEngine
+from repro.sim import ReconstructionHead
+from test_drift import energy_detector
 from test_fused import small_detector
 from test_grouped import NO_NORM, mixed_groups
 from _jaxpr import equations
@@ -159,6 +161,29 @@ def test_byte_counters_of_a_grouped_fleet(megakernel):
         h2d = sum(4 * (2 * length * 2 * 4 + 8) for length in BLOCKS)
         d2h = len(BLOCKS) * (2 * 2 + 3 * 2 * 1) * 4
     assert (engine.stats.h2d_bytes, engine.stats.d2h_bytes) == (h2d, d2h)
+
+
+@pytest.mark.parametrize("async_depth", [0, 1], ids=["sync", "async"])
+def test_finalize_carries_the_recalibration_counters(tmp_path, async_depth):
+    model, params = energy_detector(WINDOW, 2)
+    engine = StreamEngine(
+        model, params, n_streams=5, window=WINDOW, stride=STRIDE,
+        n_features=2, head=ReconstructionHead(threshold=1.0, target_fpr=0.2),
+        adapt=AdaptConfig(capacity=3, min_count=2), async_depth=async_depth,
+        **NO_NORM)
+    plain, got = traced(tmp_path, engine)
+    lo, hi = got["window"]
+    # One recalibration per finalized step: the (5, 3) f32 ring and the
+    # (5,) int32 counts come to the host for it, apart from d2h_bytes.
+    recals = len(BLOCKS) - async_depth
+    assert engine.stats.recalibrations == recals
+    assert engine.stats.calib_d2h_bytes == recals * (5 * 3 * 4 + 5 * 4)
+    assert engine.stats.d2h_bytes == len(BLOCKS) * 5 * 1 * 4 - \
+        async_depth * 5 * 1 * 4
+    assert S.stat_sum(got["stages"], "serve.finalize", "recalibrations",
+                      lo, hi) == engine.stats.recalibrations
+    assert S.stat_sum(got["stages"], "serve.finalize", "calib_d2h_bytes",
+                      lo, hi) == engine.stats.calib_d2h_bytes
 
 
 def scoped(jaxpr, scope: str, inside: bool = False):
